@@ -13,6 +13,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from benchmarks import (bench_arch_dims, bench_distortion,
                             bench_kernels, bench_refinement, bench_serving,
                             bench_storage, bench_streaming,

@@ -30,7 +30,7 @@ candidate generation (refine, rerank, merge, cost fold) is shared.
   ``front_rep`` pytree (IVF: the coarse centroids) replicated.
 
 * ``ShardedExecutor`` — runs front → refine → rerank per shard under
-  ``repro.compat.shard_map`` (queries replicated, database sharded).
+  ``jax.shard_map`` (queries replicated, database sharded).
   Equivalence with the unsharded ``SearchExecutor`` is exact, not
   approximate, because every data-dependent decision is globalized:
 
@@ -79,7 +79,6 @@ from repro.anns.executor import (_accumulate, _attach_ledger, _cat,
 from repro.anns.stages import (Candidates, Counters, adc_score,
                                fold_graph_front_cost, fold_ivf_front_cost,
                                graph_for, rank_centroid_lists)
-from repro.compat import shard_map
 from repro.core.decomposition import RecordScalars
 from repro.core.estimator import pooled_k_smallest
 from repro.core.trq import TRQCodes, TRQLevel
@@ -533,10 +532,10 @@ def _sharded_search(mesh, queries, qvalid, front_rep, codebook, trq_model,
                     front_args: tuple):
     body = partial(_shard_body, dim=dim, k=k, budget=budget, bound=bound,
                    z=z, backend=backend, front=front, front_args=front_args)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(), P(), P(), P(), P(), P(AXIS), P(AXIS)),
-                   out_specs=(P(), P(), P(AXIS)),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(), P(), P(), P(), P(), P(AXIS), P(AXIS)),
+                       out_specs=(P(), P(), P(AXIS)),
+                       check_vma=False)
     return fn(queries, qvalid, front_rep, codebook, trq_model, front_db,
               rec_db)
 

@@ -38,12 +38,15 @@ def build_features(d0: jax.Array, d_ip: jax.Array, delta_sq: jax.Array,
 
 def fit(features: jax.Array, target: jax.Array, *, ridge: float = 1e-6
         ) -> CalibrationModel:
-    """OLS (tiny ridge for conditioning) with intercept. features (N,F)."""
+    """OLS (tiny ridge for conditioning) with intercept. features (N,F).
+    Runs at HIGHEST matmul precision, so a TPU fits the same model as a
+    CPU (its default f32 matmul rounds through bf16)."""
     n = features.shape[0]
     a = jnp.concatenate([features, jnp.ones((n, 1), features.dtype)], axis=1)
-    gram = a.T @ a + ridge * jnp.eye(a.shape[1], dtype=a.dtype)
-    coef = jnp.linalg.solve(gram, a.T @ target)
-    pred = a @ coef
+    with jax.default_matmul_precision("highest"):
+        gram = a.T @ a + ridge * jnp.eye(a.shape[1], dtype=a.dtype)
+        coef = jnp.linalg.solve(gram, a.T @ target)
+        pred = a @ coef
     resid_std = jnp.std(target - pred)
     return CalibrationModel(w=coef[:-1], bias=coef[-1], resid_std=resid_std)
 

@@ -25,6 +25,7 @@ from repro.core.decomposition import RecordScalars, compute_scalars
 from repro.core.estimator import (ProgressiveState, cauchy_margin,
                                   refine_level, residual_ip_estimate,
                                   topk_threshold)
+from repro.core.rows import map_rows
 from repro.core.ternary import TernaryCode, reconstruct, ternary_encode
 
 
@@ -70,25 +71,35 @@ def encode_database(x: jax.Array, x_c: jax.Array, *, num_levels: int = 1
 
     Returns the packed TRQCodes (with an identity calibration model — call
     ``calibrate`` to fit) and the raw per-level TernaryCodes (test hooks).
+    Every quantity is per-row, so rows are encoded in chunks (the (N, D)
+    sort and residual temporaries exist for one chunk only).
     """
-    delta = x - x_c
-    levels: list[TRQLevel] = []
-    raw: list[TernaryCode] = []
-    resid = delta
-    for _ in range(num_levels):
-        tc = ternary_encode(resid)
-        raw.append(tc)
-        levels.append(TRQLevel(
-            packed=packing.pack_ternary(tc.code),
-            proj=(tc.norm * tc.rho).astype(jnp.float32),
-            norm=tc.norm,
-            rho=tc.rho,
-        ))
-        resid = resid - reconstruct(tc)
-    scalars = compute_scalars(x, x_c, rho=raw[0].rho)
+    levels, raw, scalars = _encode_rows(x, x_c, num_levels=num_levels)
     codes = TRQCodes(dim=x.shape[-1], levels=tuple(levels), scalars=scalars,
                      model=calib.identity_model())
-    return codes, raw
+    return codes, list(raw)
+
+
+@partial(jax.jit, static_argnames=("num_levels",))
+def _encode_rows(x: jax.Array, x_c: jax.Array, *, num_levels: int):
+    def rows(xb, xcb):
+        delta = xb - xcb
+        levels: list[TRQLevel] = []
+        raw: list[TernaryCode] = []
+        resid = delta
+        for _ in range(num_levels):
+            tc = ternary_encode(resid)
+            raw.append(tc)
+            levels.append(TRQLevel(
+                packed=packing.pack_ternary(tc.code),
+                proj=(tc.norm * tc.rho).astype(jnp.float32),
+                norm=tc.norm,
+                rho=tc.rho,
+            ))
+            resid = resid - reconstruct(tc)
+        return levels, raw, compute_scalars(xb, xcb, rho=raw[0].rho)
+
+    return map_rows(rows, x, x_c)
 
 
 def encode_rows(x_new: jax.Array, x_c_new: jax.Array, *, num_levels: int = 1,

@@ -38,11 +38,14 @@ def make_embeddings(key: jax.Array, n: int, d: int, *, clusters: int = 64,
 
 def brute_force_topk(x: jax.Array, queries: jax.Array, k: int,
                      *, block: int = 256) -> jax.Array:
-    """Exact top-k under L2 (blocked over queries to bound memory)."""
+    """Exact top-k under L2 (blocked over queries to bound memory).  The
+    matmul runs at HIGHEST precision: a TPU's default f32 matmul rounds
+    through bf16, which would make the reference approximate."""
     x_sq = jnp.sum(x * x, axis=-1)
 
     def one_block(qb):
-        d = x_sq[None, :] - 2.0 * (qb @ x.T)   # + ||q||² (rank-invariant)
+        d = x_sq[None, :] - 2.0 * jnp.matmul(   # + ||q||² (rank-invariant)
+            qb, x.T, precision=jax.lax.Precision.HIGHEST)
         _, idx = jax.lax.top_k(-d, k)
         return idx
 

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.quant.kmeans import assign, kmeans
+from repro.quant.kmeans import assign, assign_rows, kmeans
 
 
 @partial(jax.tree_util.register_dataclass,
@@ -68,10 +68,11 @@ def build(key: jax.Array, x: jax.Array, nlist: int, *, iters: int = 20,
     """Train centroids and fill inverted lists (host-side fill, device arrays
     out).  cap = cap_factor × N/nlist bounds skew; a hotter list spills the
     capacity rather than silently dropping members (the pre-vectorization
-    fill loop lost any record past cap)."""
+    fill loop lost any record past cap).  Assignment runs in row chunks,
+    so no (N, nlist) temporary is built."""
     n = x.shape[0]
     centroids = kmeans(key, x, nlist, iters)
-    ids = np.asarray(assign(x, centroids))
+    ids = np.asarray(assign_rows(x, centroids))
     cap = int(cap_factor * n / nlist) + 1
     lists, lens, _ = fill_lists(ids, nlist, cap)
     return IVFIndex(centroids=jnp.asarray(centroids),

@@ -2,8 +2,8 @@
 
 Handle padding to block multiples, build the query digit planes / parameter
 vectors, and enforce the per-step VMEM budget.  Backend dispatch (compiled
-on TPU, interpreter elsewhere) happens inside the kernels' own
-``interpret=None`` auto-detection.  The wrappers take the same logical
+on TPU, interpreter elsewhere) happens when a kernel is traced, through
+its ``interpret=None`` default.  The wrappers take the same logical
 arguments as the pure-jnp oracles in ref.py.
 """
 
@@ -21,9 +21,8 @@ from repro.kernels.ternary_refine import (ternary_refine,
                                           ternary_refine_fused,
                                           ternary_refine_fused_bounds)
 
-_ON_TPU = jax.default_backend() == "tpu"
-
-#: Per-core VMEM capacity the kernels budget against (v4/v5e ≈ 16 MiB).
+#: Per-core VMEM the kernels budget against: the TPU compiler's default
+#: scoped-VMEM limit on v4/v5e (16 MiB); the kernels set no other limit.
 VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 
 
@@ -35,9 +34,11 @@ def _check_vmem_budget(*, what: str, block_c: int, g: int, c_pad: int,
                        num_levels: int = 1, fused: bool = False) -> None:
     """Reject block/level configurations whose per-step working set cannot
     fit in VMEM.  Counted per grid step: double-buffered input blocks
-    (codes + scalars + level scalars + digit planes + params) plus, for the
-    fused kernels, the full-candidate-set scratch (est/lo/hi/alive/delta)
-    and resident outputs that persist across level segments."""
+    (codes + scalars + level scalars + digit planes + params) and the
+    unpacked-code temporaries, plus, for the fused kernels, the
+    full-candidate-set scratch (est/lo/hi/alive/delta), the resident
+    (C/BC, BC) output blocks (double-buffered; at most three) and the
+    threshold pass's full-candidate-set temporaries."""
     per_step = (block_c * g                # packed codes (uint8)
                 + block_c * 8 * 4          # level-0 scalars
                 + 5 * g * 4                # query digit planes
@@ -45,9 +46,11 @@ def _check_vmem_budget(*, what: str, block_c: int, g: int, c_pad: int,
     if fused:
         per_step += block_c * 4 * 4        # level scalars
     total = 2 * per_step                   # double buffering
+    total += 4 * block_c * g * 4           # unpacked bytes, digits, acc
     if fused:
         total += 5 * c_pad * 4             # est/lo/hi/alive/delta scratch
-        total += (2 * c_pad + 2 * num_levels) * 4   # resident outputs
+        total += 3 * 2 * c_pad * 4         # resident output blocks
+        total += 6 * c_pad * 4             # threshold-pass temporaries
     if total > VMEM_BUDGET_BYTES:
         raise VMEMBudgetError(
             f"{what}: block_c={block_c} x {num_levels} level(s) over "
@@ -149,11 +152,12 @@ def refine_scores_batch(packed: jax.Array, q: jax.Array, d0: jax.Array,
 def _fused_inputs(packed_levels, q, d0, delta_sq, cross, norm, rho, valid,
                   is_delta, lvl_proj, lvl_norm, lvl_rho, w, bias, resid_std,
                   z, block_c):
-    """Shared input assembly for the fused kernels: gather/stack the
-    level-0 scalar plane (valid + is_delta flags in slots 5/6), the
-    per-level [proj, norm, rho] planes, and the per-query params with
-    [z·resid_std, resid_std] in the extra slots; pad candidates to a
-    block_c multiple (padded slots have valid=0, so they never survive)."""
+    """Shared input assembly for the fused kernels: stack the level-0
+    scalar planes (valid + is_delta flags in rows 5/6), the per-level
+    [proj, norm, rho] planes, and the per-query params with
+    [z·resid_std, resid_std] in the extra slots; lay candidates along the
+    minor axis (the kernels' lane-major layout) and pad them to a block_c
+    multiple (padded slots have valid=0, so they never survive)."""
     l, nq, c, g = packed_levels.shape
     rs = jnp.asarray(resid_std, jnp.float32)
     extra = jnp.stack([jnp.float32(z) * rs, rs])
@@ -161,14 +165,15 @@ def _fused_inputs(packed_levels, q, d0, delta_sq, cross, norm, rho, valid,
     zeros = jnp.zeros_like(d0)
     scalars = jnp.stack(
         [d0, delta_sq, cross, norm, rho, valid.astype(jnp.float32),
-         is_delta.astype(jnp.float32), zeros], axis=-1)         # (Q, C, 8)
+         is_delta.astype(jnp.float32), zeros], axis=1)          # (Q, 8, C)
     level_scalars = jnp.stack(
         [lvl_proj, lvl_norm, lvl_rho, jnp.zeros_like(lvl_proj)],
-        axis=-1)                                                # (L, Q, C, 4)
-    packed_p, c0 = _pad_axis(packed_levels, 2, block_c)
-    scalars_p, _ = _pad_axis(scalars.astype(jnp.float32), 1, block_c)
-    lvl_p, _ = _pad_axis(level_scalars.astype(jnp.float32), 2, block_c)
-    return packed_p, q_planes, scalars_p, lvl_p, params, c0
+        axis=2)                                                 # (L, Q, 4, C)
+    packed_p, c0 = _pad_axis(jnp.swapaxes(packed_levels, 2, 3), 3, block_c)
+    scalars_p, _ = _pad_axis(scalars.astype(jnp.float32), 2, block_c)
+    lvl_p, _ = _pad_axis(level_scalars.astype(jnp.float32), 3, block_c)
+    return (packed_p, jnp.swapaxes(q_planes, 1, 2), scalars_p, lvl_p, params,
+            c0)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "bound", "block_c"))
@@ -201,7 +206,7 @@ def fused_refine_scores_batch(packed_levels: jax.Array, q: jax.Array,
     packed_p, q_planes, scalars_p, lvl_p, params, c0 = inputs
     l, g = packed_levels.shape[0], packed_levels.shape[3]
     _check_vmem_budget(what="fused_refine_scores_batch", block_c=block_c,
-                       g=g, c_pad=packed_p.shape[2], num_levels=l,
+                       g=g, c_pad=packed_p.shape[3], num_levels=l,
                        fused=True)
     est, alive, counts = ternary_refine_fused(
         packed_p, q_planes, scalars_p, lvl_p, params, k=k, bound=bound,
@@ -230,7 +235,7 @@ def fused_refine_bounds_batch(packed_levels: jax.Array, q: jax.Array,
     packed_p, q_planes, scalars_p, lvl_p, params, c0 = inputs
     l, g = packed_levels.shape[0], packed_levels.shape[3]
     _check_vmem_budget(what="fused_refine_bounds_batch", block_c=block_c,
-                       g=g, c_pad=packed_p.shape[2], num_levels=l,
+                       g=g, c_pad=packed_p.shape[3], num_levels=l,
                        fused=True)
     est, lo, hi = ternary_refine_fused_bounds(
         packed_p, q_planes, scalars_p, lvl_p, params, bound=bound,
@@ -243,5 +248,4 @@ def adc_scores(codes: jax.Array, lut: jax.Array, *, block_c: int = 128
                ) -> jax.Array:
     """PQ-ADC distances for a candidate batch → (C,)."""
     codes_p, c0 = _pad_rows(codes, block_c)
-    return pq_adc(codes_p, lut.astype(jnp.float32), block_c=block_c,
-                  interpret=not _ON_TPU)[:c0]
+    return pq_adc(codes_p, lut.astype(jnp.float32), block_c=block_c)[:c0]

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ternary_refine import resolve_interpret
+
 
 def _adc_kernel(codes_ref, lut_ref, out_ref):
     codes = codes_ref[...].astype(jnp.int32)            # (BC, M)
@@ -34,7 +36,7 @@ def _adc_kernel(codes_ref, lut_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
 def pq_adc(codes: jax.Array, lut: jax.Array, *, block_c: int = 128,
-           interpret: bool = True) -> jax.Array:
+           interpret: bool | None = None) -> jax.Array:
     """codes (C, M) uint8, lut (M, K) f32 → distances (C,) f32.
 
     C must be a multiple of block_c (ops.py pads).  VMEM: the (BC, M, K)
@@ -52,6 +54,6 @@ def pq_adc(codes: jax.Array, lut: jax.Array, *, block_c: int = 128,
         ],
         out_specs=pl.BlockSpec((block_c, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((c, 1), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(codes, lut)
     return out[:, 0]

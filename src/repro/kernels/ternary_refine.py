@@ -32,10 +32,12 @@ Three kernels share the digit-plane scoring body:
 Layout note: base-3 digit i of byte g holds dim 5g+i, so the query is
 pre-arranged into 5 digit planes of (G,) (see ref.make_query_planes) and
 unpacking is 5 div/mod passes over the byte block — no reshapes, no
-gathers, fully vectorized on 8×128 VPU tiles.
+gathers, fully vectorized on 8×128 VPU tiles.  The fused kernels take
+their candidates along lanes ((G, C) codes, (rows, C) scalar planes), the
+layout the TPU compiler tiles without padding or relayout.
 
-``interpret`` defaults to backend auto-detection (compiled on TPU,
-interpreter elsewhere); pass an explicit bool only to force a mode.
+``interpret=None`` picks the mode when the kernel is traced (compiled on
+TPU, interpreter elsewhere); pass an explicit bool only to force a mode.
 """
 
 from __future__ import annotations
@@ -49,50 +51,46 @@ from jax.experimental.pallas import tpu as pltpu
 
 _POW3 = (1, 3, 9, 27, 81)
 
-_ON_TPU = jax.default_backend() == "tpu"
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """None → decided when the kernel is traced: compiled when JAX's
+    default backend is a TPU, the interpreter everywhere else.  Nothing
+    is asked of the device when this module is imported."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
 
 
-def _resolve_interpret(interpret: bool | None) -> bool:
-    """None → auto-detect: compiled on TPU, interpreter everywhere else."""
-    return (not _ON_TPU) if interpret is None else bool(interpret)
-
-
-def _block_align(y, qplanes):
+def _block_align(y, plane, axis: int):
     """Digit-plane unpack + ternary inner product for one candidate block.
 
-    y (BC, G) int32 packed bytes, qplanes (5, G) → align (BC,) = Σc·q/√k,
-    the ⟨q, e_code⟩ term every level's estimate update consumes.
+    y int32 packed bytes with the G byte axis at ``axis``; ``plane(i)`` is
+    query digit plane i, broadcastable against y → align = Σc·q/√k, the
+    ⟨q, e_code⟩ term every level's estimate update consumes, with the G
+    axis reduced (kept as size 1).
     """
     acc = jnp.zeros(y.shape, jnp.float32)
     kcnt = jnp.zeros(y.shape, jnp.int32)
     for i in range(5):
-        digit = (y // _POW3[i]) % 3 - 1            # (BC, G) ∈ {-1,0,1}
+        digit = (y // _POW3[i]) % 3 - 1            # ∈ {-1,0,1}
         trit = digit.astype(jnp.float32)
-        acc = acc + trit * qplanes[i, :][None, :]
+        acc = acc + trit * plane(i)
         kcnt = kcnt + digit * digit
-    raw = jnp.sum(acc, axis=1)                     # Σ c·q        (BC,)
-    k = jnp.sum(kcnt, axis=1).astype(jnp.float32)  # ||c||²       (BC,)
+    raw = jnp.sum(acc, axis=axis, keepdims=True)   # Σ c·q
+    k = jnp.sum(kcnt, axis=axis, keepdims=True).astype(jnp.float32)  # ||c||²
     return raw / jnp.sqrt(jnp.maximum(k, 1.0))     # Σ c·q / √k
 
 
-def _score_block(y, qplanes, scal, params):
+def _score_block(align, rec, params):
     """Shared level-0 scoring math: one candidate block of one query.
 
-    y (BC, G) int32 packed bytes, qplanes (5, G), scal (BC, 8), params (8,)
-    → (est, est_raw, margin), each (BC,).  All kernels call this; only the
-    ref slicing differs between the single-query and batched grids.
+    align = Σc·q/√k; rec = (d0, ||δ||², ⟨x_c,δ⟩, ||δ||, rho) planes shaped
+    like align; params = (qn, w0..w3, bias, …) scalars → (est, est_raw,
+    margin).  Elementwise only, so each kernel lays its candidates out as
+    its grid needs.
     """
-    qn = params[0]
-    w0, w1, w2, w3, bias = params[1], params[2], params[3], params[4], \
-        params[5]
-
-    align = _block_align(y, qplanes)
-
-    d0 = scal[:, 0]
-    delta_sq = scal[:, 1]
-    cross = scal[:, 2]
-    norm = scal[:, 3]
-    rho = scal[:, 4]
+    qn, w0, w1, w2, w3, bias = params[:6]
+    d0, delta_sq, cross, norm, rho = rec
 
     e_align = align / jnp.maximum(qn, 1e-30)
     d_ip = -2.0 * norm * rho * align
@@ -105,19 +103,23 @@ def _score_block(y, qplanes, scal, params):
 
 
 def _kth_smallest(vals, k: int):
-    """kth-smallest VALUE of a 1-D vector (the pruning threshold τ).
+    """kth-smallest VALUE of a 2-D array (the pruning threshold τ).
 
     Matches ``estimator.pooled_k_smallest`` on the same multiset: the kth
-    order statistic is tie-invariant, so extracting k−1 minima (masking one
-    occurrence each round with an iota match) and taking the remaining min
-    is exactly the value ``lax.top_k`` would return.  k is static and
-    small (final_k), so the loop unrolls to k VPU reductions.
+    order statistic is tie-invariant, so masking one occurrence of the
+    minimum per round (the lowest flat index holding it) k−1 times and
+    taking the remaining min is exactly the value ``lax.top_k`` would
+    return.  k is static and small (final_k), so the loop unrolls to
+    3(k−1)+1 VPU reductions.
     """
+    rows, cols = vals.shape
+    flat = (jax.lax.broadcasted_iota(jnp.int32, vals.shape, 0) * cols
+            + jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1))
     v = vals
     for _ in range(k - 1):
-        idx = jnp.argmin(v)
-        iota = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
-        v = jnp.where(iota == idx, jnp.inf, v)
+        m = jnp.min(v, keepdims=True)
+        first = jnp.min(jnp.where(v == m, flat, rows * cols), keepdims=True)
+        v = jnp.where(flat == first, jnp.inf, v)
     return jnp.min(v)
 
 
@@ -131,15 +133,14 @@ def _level0_bounds(est, est_raw, margin, params, bound: str):
     raise ValueError(f"unknown bound {bound!r}")
 
 
-def _deeper_bounds(est_prev, y, qplanes, lsc, params):
+def _deeper_bounds(est_prev, align, lsc, params):
     """Level-ℓ≥1 stacking for one block: est −= 2·proj·align, certified
     margin 2·||q||·||δ_rem|| + resid_std (what trq.progressive_search
-    computes).  lsc (BC, 4) = [proj, norm, rho, ·]."""
+    computes).  lsc = level-ℓ (proj, norm, rho) planes."""
     qn, resid_std = params[0], params[7]
-    align = _block_align(y, qplanes)
-    est = est_prev - 2.0 * lsc[:, 0] * align
-    rem = lsc[:, 1] * jnp.sqrt(
-        jnp.clip(1.0 - lsc[:, 2] * lsc[:, 2], 0.0, 1.0))
+    proj, norm, rho = lsc
+    est = est_prev - 2.0 * proj * align
+    rem = norm * jnp.sqrt(jnp.clip(1.0 - rho * rho, 0.0, 1.0))
     marg = 2.0 * qn * rem + resid_std
     return est, est - marg, est + marg
 
@@ -147,14 +148,22 @@ def _deeper_bounds(est_prev, y, qplanes, lsc, params):
 # --------------------------------------------------------- level-0 kernels
 
 
+def _score_rows(y, qplanes, scal, params):
+    """Candidate-major level-0 scoring: y (BC, G) bytes, qplanes (5, G),
+    scal (BC, 8), params (8,) → (est, est_raw, margin), each (BC, 1)."""
+    align = _block_align(y.astype(jnp.int32),
+                         lambda i: qplanes[i, :][None, :], axis=1)
+    rec = tuple(scal[:, j:j + 1] for j in range(5))
+    return _score_block(align, rec, tuple(params[j] for j in range(8)))
+
+
 def _refine_kernel(packed_ref, qplanes_ref, scal_ref, params_ref, out_ref):
     """One candidate block: (BC, G) bytes → (BC, 3) [est, est_raw, margin]."""
-    est, est_raw, margin = _score_block(packed_ref[...].astype(jnp.int32),
-                                        qplanes_ref[...], scal_ref[...],
-                                        params_ref[0])
-    out_ref[:, 0] = est
-    out_ref[:, 1] = est_raw
-    out_ref[:, 2] = margin
+    est, est_raw, margin = _score_rows(packed_ref[...], qplanes_ref[...],
+                                       scal_ref[...], params_ref[0])
+    out_ref[:, 0:1] = est
+    out_ref[:, 1:2] = est_raw
+    out_ref[:, 2:3] = margin
 
 
 def _refine_kernel_batch(packed_ref, qplanes_ref, scal_ref, params_ref,
@@ -166,12 +175,11 @@ def _refine_kernel_batch(packed_ref, qplanes_ref, scal_ref, params_ref,
     executor's batched level-0 datapath (the fully fused multi-level loop
     is ``_fused_kernel`` below).
     """
-    est, est_raw, margin = _score_block(packed_ref[0].astype(jnp.int32),
-                                        qplanes_ref[0], scal_ref[0],
-                                        params_ref[0])
-    out_ref[0, :, 0] = est
-    out_ref[0, :, 1] = est_raw
-    out_ref[0, :, 2] = margin
+    est, est_raw, margin = _score_rows(packed_ref[0], qplanes_ref[0],
+                                       scal_ref[0], params_ref[0])
+    out_ref[0, :, 0:1] = est
+    out_ref[0, :, 1:2] = est_raw
+    out_ref[0, :, 2:3] = margin
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
@@ -189,7 +197,7 @@ def ternary_refine_batch(packed: jax.Array, q_planes: jax.Array,
     C must be a multiple of block_c (ops.py pads).  The grid walks queries
     in the outer dimension so each query's candidate blocks stream through
     VMEM back-to-back with its (5, G) digit planes held resident.
-    ``interpret=None`` auto-detects the backend (compiled on TPU).
+    ``interpret=None`` picks the mode at trace time (compiled on TPU).
     """
     nq, c, g = packed.shape
     assert c % block_c == 0, (c, block_c)
@@ -205,7 +213,7 @@ def ternary_refine_batch(packed: jax.Array, q_planes: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, block_c, 4), lambda qi, ci: (qi, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((nq, c, 4), jnp.float32),
-        interpret=_resolve_interpret(interpret),
+        interpret=resolve_interpret(interpret),
     )(packed, q_planes, scalars, params)[..., :3]
 
 
@@ -221,7 +229,7 @@ def ternary_refine(packed: jax.Array, q_planes: jax.Array, scalars: jax.Array,
     block_c·G bytes of codes + 5·G query floats + block_c·8 scalars —
     e.g. 512×154 ≈ 77 KiB codes, a small slice of a TPU core's ~16 MiB
     VMEM, so several steps double-buffer (ops.py enforces the budget).
-    ``interpret=None`` auto-detects the backend (compiled on TPU).
+    ``interpret=None`` picks the mode at trace time (compiled on TPU).
     """
     c, g = packed.shape
     assert c % block_c == 0, (c, block_c)
@@ -237,7 +245,7 @@ def ternary_refine(packed: jax.Array, q_planes: jax.Array, scalars: jax.Array,
         ],
         out_specs=pl.BlockSpec((block_c, 4), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((c, 4), jnp.float32),
-        interpret=_resolve_interpret(interpret),
+        interpret=resolve_interpret(interpret),
     )(packed, q_planes, scalars, params)[:, :3]
 
 
@@ -245,50 +253,66 @@ def ternary_refine(packed: jax.Array, q_planes: jax.Array, scalars: jax.Array,
 #
 # Grid (Q, L, C/BC): for each query, the level segments run sequentially
 # (TPU grids are sequential on a core), each walking the candidate blocks.
-# The running estimate, certified (lo, hi) interval, alive mask and
-# delta-page flag live in (C,) VMEM scratch that persists across segments;
-# per-level thresholds live in SMEM scratch.  Only the FINAL estimate,
-# alive mask and per-level survivor counts ever reach HBM.
+# Candidates lie along lanes: a code block is (G, BC) bytes, every scalar
+# plane a (1, BC) row, so block reductions land lane-dense.  The running
+# estimate, certified (lo, hi) interval, alive mask and delta-page flag
+# live in (C/BC, BC) VMEM scratch that persists across segments (row ci
+# holds block ci); per-level thresholds live in SMEM scratch and the
+# per-query params are read from SMEM.  Only the FINAL estimate, alive
+# mask and per-level survivor counts ever reach HBM.
+
+
+def _planes(ref, n: int) -> tuple:
+    """The first n (1, BC) rows of a (rows, BC) scalar-plane block."""
+    return tuple(ref[j:j + 1, :] for j in range(n))
+
+
+def _align_rows(packed_ref, qplanes_ref):
+    """Lane-major ``_block_align``: (G, BC) bytes against (G, 5) planes →
+    (1, BC)."""
+    qp = qplanes_ref[...]
+    return _block_align(packed_ref[...].astype(jnp.int32),
+                        lambda i: qp[:, i:i + 1], axis=0)
 
 
 def _fused_kernel(packed_ref, qplanes_ref, scal0_ref, lvls_ref, params_ref,
                   est_out, alive_out, counts_out,
                   est_s, lo_s, hi_s, alive_s, delta_s, tau_s, *,
-                  num_levels: int, n_blocks: int, block_c: int, k: int,
-                  bound: str):
+                  num_levels: int, n_blocks: int, k: int, bound: str):
     """Fully fused datapath: score, stack, threshold, mask, count — on chip.
 
-    scal0 (BC, 8) = [d0, ||δ||², ⟨x_c,δ⟩, ||δ||, rho, valid, is_delta, ·];
-    lvls (BC, 4) = level-ℓ [proj, norm, rho, ·];
-    params (8,) = [qn, w0..w3, bias, z·resid_std, resid_std].
-    counts_out (1, 2L): slots [0, L) hold Σ alive after each level, slots
-    [L, 2L) the delta-page survivor split the ledger bills to delta:cxl.
+    scal0 (8, BC) rows = [d0, ||δ||², ⟨x_c,δ⟩, ||δ||, rho, valid, is_delta, ·];
+    lvls (4, BC) rows = level-ℓ [proj, norm, rho, ·];
+    params (Q, 8) in SMEM, row = [qn, w0..w3, bias, z·resid_std, resid_std].
+    counts_out (Q·2L,) in SMEM: per query, slots [0, L) hold Σ alive after
+    each level, slots [L, 2L) the delta-page survivor split the ledger
+    bills to delta:cxl.
     """
+    qi = pl.program_id(0)
     lv = pl.program_id(1)
     ci = pl.program_id(2)
-    blk = pl.ds(ci * block_c, block_c)
-    params = params_ref[0]
-    y = packed_ref[0, 0].astype(jnp.int32)
-    qplanes = qplanes_ref[0]
+    row = pl.ds(ci, 1)
+    params = tuple(params_ref[qi, j] for j in range(8))
+    align = _align_rows(packed_ref, qplanes_ref)
 
     @pl.when(lv == 0)
     def _level0():
-        scal = scal0_ref[0]
-        est, est_raw, margin = _score_block(y, qplanes, scal, params)
+        est, est_raw, margin = _score_block(align, _planes(scal0_ref, 5),
+                                            params)
         lo, hi = _level0_bounds(est, est_raw, margin, params, bound)
-        est_s[blk] = est
-        lo_s[blk] = lo
-        hi_s[blk] = hi
-        alive_s[blk] = scal[:, 5]
-        delta_s[blk] = scal[:, 6]
+        est_s[row, :] = est
+        lo_s[row, :] = lo
+        hi_s[row, :] = hi
+        alive_s[row, :] = scal0_ref[5:6, :]
+        delta_s[row, :] = scal0_ref[6:7, :]
 
     @pl.when(lv > 0)
     def _deeper():
-        est, lo, hi = _deeper_bounds(est_s[blk], y, qplanes,
-                                     lvls_ref[0, 0], params)
-        est_s[blk] = est
-        lo_s[blk] = lo
-        hi_s[blk] = hi
+        est, lo, hi = _deeper_bounds(est_s[row, :], align,
+                                     _planes(lvls_ref, 3), params)
+        est_s[row, :] = est
+        lo_s[row, :] = lo
+        hi_s[row, :] = hi
 
     @pl.when(ci == n_blocks - 1)
     def _prune_level():
@@ -300,63 +324,71 @@ def _fused_kernel(packed_ref, qplanes_ref, scal0_ref, lvls_ref, params_ref,
         tau_s[lv] = _kth_smallest(jnp.where(amask, hi_s[...], jnp.inf), k)
         alive_new = amask & (lo_s[...] <= tau_s[lv])
         alive_s[...] = alive_new.astype(jnp.float32)
-        counts_out[0, lv] = jnp.sum(alive_new.astype(jnp.int32))
+        base = qi * (2 * num_levels)
+        counts_out[base + lv] = jnp.sum(alive_new.astype(jnp.int32))
         is_delta = delta_s[...] > 0.0
-        counts_out[0, num_levels + lv] = jnp.sum(
+        counts_out[base + num_levels + lv] = jnp.sum(
             (alive_new & is_delta).astype(jnp.int32))
 
     @pl.when(jnp.logical_and(lv == num_levels - 1, ci == n_blocks - 1))
     def _emit():
-        est_out[0, :] = est_s[...]
-        alive_out[0, :] = (alive_s[...] > 0.0).astype(jnp.int32)
+        est_out[...] = est_s[...]
+        alive_out[...] = (alive_s[...] > 0.0).astype(jnp.int32)
 
 
 def _fused_bounds_kernel(packed_ref, qplanes_ref, scal0_ref, lvls_ref,
                          params_ref, est_out, lo_out, hi_out, est_s, *,
-                         num_levels: int, n_blocks: int, block_c: int,
-                         bound: str):
+                         num_levels: int, bound: str):
     """Sharded variant: same single-launch VMEM level stacking, but emit
     each level's certified (lo, hi) instead of masking on-chip — pruning
     thresholds must be pooled ACROSS shards (a mesh collective), which
     cannot run inside a kernel.  The caller's alive chain over these
     bounds is arithmetically identical to ``_fused_kernel``'s."""
+    qi = pl.program_id(0)
     lv = pl.program_id(1)
     ci = pl.program_id(2)
-    blk = pl.ds(ci * block_c, block_c)
-    params = params_ref[0]
-    y = packed_ref[0, 0].astype(jnp.int32)
-    qplanes = qplanes_ref[0]
+    row = pl.ds(ci, 1)
+    params = tuple(params_ref[qi, j] for j in range(8))
+    align = _align_rows(packed_ref, qplanes_ref)
 
     @pl.when(lv == 0)
     def _level0():
-        est, est_raw, margin = _score_block(y, qplanes, scal0_ref[0], params)
+        est, est_raw, margin = _score_block(align, _planes(scal0_ref, 5),
+                                            params)
         lo, hi = _level0_bounds(est, est_raw, margin, params, bound)
-        est_s[blk] = est
-        lo_out[0, 0] = lo
-        hi_out[0, 0] = hi
+        est_s[row, :] = est
+        lo_out[row, :] = lo
+        hi_out[row, :] = hi
 
     @pl.when(lv > 0)
     def _deeper():
-        est, lo, hi = _deeper_bounds(est_s[blk], y, qplanes,
-                                     lvls_ref[0, 0], params)
-        est_s[blk] = est
-        lo_out[0, 0] = lo
-        hi_out[0, 0] = hi
+        est, lo, hi = _deeper_bounds(est_s[row, :], align,
+                                     _planes(lvls_ref, 3), params)
+        est_s[row, :] = est
+        lo_out[row, :] = lo
+        hi_out[row, :] = hi
 
     @pl.when(lv == num_levels - 1)
     def _emit():
-        est_out[0] = est_s[blk]
+        est_out[row, :] = est_s[row, :]
 
 
 def _fused_in_specs(block_c: int, g: int):
     """Input block specs shared by both fused kernels (grid (Q, L, B))."""
     return [
-        pl.BlockSpec((1, 1, block_c, g), lambda qi, lv, ci: (lv, qi, ci, 0)),
-        pl.BlockSpec((1, 5, g), lambda qi, lv, ci: (qi, 0, 0)),
-        pl.BlockSpec((1, block_c, 8), lambda qi, lv, ci: (qi, ci, 0)),
-        pl.BlockSpec((1, 1, block_c, 4), lambda qi, lv, ci: (lv, qi, ci, 0)),
-        pl.BlockSpec((1, 8), lambda qi, lv, ci: (qi, 0)),
+        pl.BlockSpec((None, None, g, block_c),
+                     lambda qi, lv, ci: (lv, qi, 0, ci)),
+        pl.BlockSpec((None, g, 5), lambda qi, lv, ci: (qi, 0, 0)),
+        pl.BlockSpec((None, 8, block_c), lambda qi, lv, ci: (qi, 0, ci)),
+        pl.BlockSpec((None, None, 4, block_c),
+                     lambda qi, lv, ci: (lv, qi, 0, ci)),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
     ]
+
+
+def _query_rows(nb: int, block_c: int):
+    """Output block holding one query's whole candidate set as (nb, BC)."""
+    return pl.BlockSpec((None, nb, block_c), lambda qi, lv, ci: (qi, 0, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("k", "bound", "block_c",
@@ -368,11 +400,11 @@ def ternary_refine_fused(packed: jax.Array, q_planes: jax.Array,
                          ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Persistent multi-level refine: ALL TRQ levels in one launch.
 
-    packed (L, Q, C, G) uint8 per-level per-query gathered codes;
-    q_planes (Q, 5, G); scalars (Q, C, 8) f32
+    Candidate-minor layout: packed (L, Q, G, C) uint8 per-level per-query
+    gathered codes; q_planes (Q, G, 5); scalars (Q, 8, C) f32 rows
     [d0, ||δ||², ⟨x_c,δ⟩, ||δ||, rho, valid, is_delta, ·];
-    level_scalars (L, Q, C, 4) f32 [proj, norm, rho, ·] (level-0 plane is
-    a placeholder — level 0 scores from ``scalars``); params (Q, 8) f32
+    level_scalars (L, Q, 4, C) f32 rows [proj, norm, rho, ·] (level-0 plane
+    is a placeholder — level 0 scores from ``scalars``); params (Q, 8) f32
     [qn, w0..w3, bias, z·resid_std, resid_std].
 
     Returns (est (Q, C) f32, alive (Q, C) int32, counts (Q, 2L) int32):
@@ -382,35 +414,37 @@ def ternary_refine_fused(packed: jax.Array, q_planes: jax.Array,
     round-trips.  C must be a multiple of block_c (ops.py pads) and
     ``k ≥ 1`` is the top-k pruning width.
     """
-    l, nq, c, g = packed.shape
+    l, nq, g, c = packed.shape
     assert c % block_c == 0, (c, block_c)
     nb = c // block_c
     kernel = functools.partial(_fused_kernel, num_levels=l, n_blocks=nb,
-                               block_c=block_c, k=k, bound=bound)
-    return pl.pallas_call(
+                               k=k, bound=bound)
+    est, alive, counts = pl.pallas_call(
         kernel,
         grid=(nq, l, nb),
         in_specs=_fused_in_specs(block_c, g),
         out_specs=[
-            pl.BlockSpec((1, c), lambda qi, lv, ci: (qi, 0)),
-            pl.BlockSpec((1, c), lambda qi, lv, ci: (qi, 0)),
-            pl.BlockSpec((1, 2 * l), lambda qi, lv, ci: (qi, 0)),
+            _query_rows(nb, block_c),
+            _query_rows(nb, block_c),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nq, c), jnp.float32),
-            jax.ShapeDtypeStruct((nq, c), jnp.int32),
-            jax.ShapeDtypeStruct((nq, 2 * l), jnp.int32),
+            jax.ShapeDtypeStruct((nq, nb, block_c), jnp.float32),
+            jax.ShapeDtypeStruct((nq, nb, block_c), jnp.int32),
+            jax.ShapeDtypeStruct((nq * 2 * l,), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((c,), jnp.float32),    # running estimate
-            pltpu.VMEM((c,), jnp.float32),    # certified lower bound
-            pltpu.VMEM((c,), jnp.float32),    # certified upper bound
-            pltpu.VMEM((c,), jnp.float32),    # alive mask (0/1)
-            pltpu.VMEM((c,), jnp.float32),    # delta-page flag (0/1)
+            pltpu.VMEM((nb, block_c), jnp.float32),    # running estimate
+            pltpu.VMEM((nb, block_c), jnp.float32),    # certified lower bound
+            pltpu.VMEM((nb, block_c), jnp.float32),    # certified upper bound
+            pltpu.VMEM((nb, block_c), jnp.float32),    # alive mask (0/1)
+            pltpu.VMEM((nb, block_c), jnp.float32),    # delta-page flag (0/1)
             pltpu.SMEM((l,), jnp.float32),    # per-level pruning thresholds
         ],
-        interpret=_resolve_interpret(interpret),
+        interpret=resolve_interpret(interpret),
     )(packed, q_planes, scalars, level_scalars, params)
+    return (est.reshape(nq, c), alive.reshape(nq, c),
+            counts.reshape(nq, 2 * l))
 
 
 @functools.partial(jax.jit, static_argnames=("bound", "block_c",
@@ -427,27 +461,26 @@ def ternary_refine_fused_bounds(packed: jax.Array, q_planes: jax.Array,
     lo (Q, L, C), hi (Q, L, C)) so the caller can pool each level's
     pruning threshold across a ``shard_map`` axis.  Bit-identical per
     candidate to the fused kernel (the arithmetic is shared)."""
-    l, nq, c, g = packed.shape
+    l, nq, g, c = packed.shape
     assert c % block_c == 0, (c, block_c)
     nb = c // block_c
     kernel = functools.partial(_fused_bounds_kernel, num_levels=l,
-                               n_blocks=nb, block_c=block_c, bound=bound)
-    return pl.pallas_call(
+                               bound=bound)
+    level_rows = pl.BlockSpec((None, None, nb, block_c),
+                              lambda qi, lv, ci: (qi, lv, 0, 0))
+    est, lo, hi = pl.pallas_call(
         kernel,
         grid=(nq, l, nb),
         in_specs=_fused_in_specs(block_c, g),
-        out_specs=[
-            pl.BlockSpec((1, block_c), lambda qi, lv, ci: (qi, ci)),
-            pl.BlockSpec((1, 1, block_c), lambda qi, lv, ci: (qi, lv, ci)),
-            pl.BlockSpec((1, 1, block_c), lambda qi, lv, ci: (qi, lv, ci)),
-        ],
+        out_specs=[_query_rows(nb, block_c), level_rows, level_rows],
         out_shape=[
-            jax.ShapeDtypeStruct((nq, c), jnp.float32),
-            jax.ShapeDtypeStruct((nq, l, c), jnp.float32),
-            jax.ShapeDtypeStruct((nq, l, c), jnp.float32),
+            jax.ShapeDtypeStruct((nq, nb, block_c), jnp.float32),
+            jax.ShapeDtypeStruct((nq, l, nb, block_c), jnp.float32),
+            jax.ShapeDtypeStruct((nq, l, nb, block_c), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((c,), jnp.float32),    # running estimate
+            pltpu.VMEM((nb, block_c), jnp.float32),    # running estimate
         ],
-        interpret=_resolve_interpret(interpret),
+        interpret=resolve_interpret(interpret),
     )(packed, q_planes, scalars, level_scalars, params)
+    return est.reshape(nq, c), lo.reshape(nq, l, c), hi.reshape(nq, l, c)

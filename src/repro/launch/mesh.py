@@ -41,7 +41,10 @@ def make_search_mesh(n: int | None = None):
             f"make_search_mesh({n}) needs {n} devices but only {avail} are "
             f"visible; set XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{n} before the first jax call (host-platform meshes)")
-    return jax.make_mesh((n,), ("search",))
+    # Auto axes: the datapath places its arrays with NamedShardings and
+    # partitions the search with shard_map, not with explicit sharding types
+    return jax.make_mesh((n,), ("search",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def mesh_axis_sizes(mesh) -> dict[str, int]:

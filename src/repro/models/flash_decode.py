@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def _local_attn(q, k, v, pos, window, *, shard_axis: str, n_rep: int):
     """One shard's partial attention.
@@ -66,7 +64,7 @@ def flash_decode(q, ck, cv, pos, *, mesh, dp_axes: tuple, n_rep: int,
     (dp, model) on (B, S).  Returns (B,1,H,hd) sharded on B only."""
     dp = tuple(dp_axes) if dp_axes else None
     fn = partial(_local_attn, shard_axis=shard_axis, n_rep=n_rep)
-    return shard_map(
+    return jax.shard_map(
         lambda qq, kk, vv: fn(qq, kk, vv, pos, window),
         mesh=mesh,
         in_specs=(P(dp, None, None, None), P(dp, shard_axis, None, None),

@@ -2,7 +2,9 @@
 centroids and PQ sub-codebooks.
 
 Distance trick: argmin_c ||x−c||² = argmin_c (||c||² − 2x·c), so assignment
-is one matmul (MXU-friendly) — no (N, K, D) intermediate.
+is one matmul (MXU-friendly) — no (N, K, D) intermediate.  Assignment and
+the one-hot centroid sums run in row chunks (``core.rows``), so neither the
+(N, K) score matrix nor the (N, K) one-hot ever exists whole.
 """
 
 from __future__ import annotations
@@ -12,6 +14,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.rows import map_rows, sum_rows
+
 
 def assign(x: jax.Array, centroids: jax.Array) -> jax.Array:
     """Nearest-centroid ids for x (N, D) against centroids (K, D)."""
@@ -20,11 +24,29 @@ def assign(x: jax.Array, centroids: jax.Array) -> jax.Array:
     return jnp.argmin(c_sq[None, :] - 2.0 * scores, axis=-1)
 
 
-def _update(x: jax.Array, ids: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
-    """Mean of members per centroid (segment-sum) + member counts."""
+@jax.jit
+def assign_rows(x: jax.Array, centroids: jax.Array) -> jax.Array:
+    """``assign`` over row chunks of x (same ids, bounded scores)."""
+    return map_rows(lambda xb: assign(xb, centroids), x)
+
+
+def _fit(x: jax.Array, centroids: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Nearest-centroid ids + squared distance to that centroid."""
+    ids = assign(x, centroids)
+    return ids, jnp.sum((x - centroids[ids]) ** 2, axis=-1)
+
+
+def _member_sums(x: jax.Array, ids: jax.Array, k: int
+                 ) -> tuple[jax.Array, jax.Array]:
+    """Per-centroid sum of members (segment-sum) + member counts."""
     one_hot = jax.nn.one_hot(ids, k, dtype=x.dtype)           # (N, K)
-    counts = jnp.sum(one_hot, axis=0)                         # (K,)
-    sums = one_hot.T @ x                                      # (K, D)
+    return one_hot.T @ x, jnp.sum(one_hot, axis=0)            # (K, D), (K,)
+
+
+def _update(x: jax.Array, ids: jax.Array, k: int
+            ) -> tuple[jax.Array, jax.Array]:
+    """Mean of members per centroid + member counts."""
+    sums, counts = sum_rows(lambda xb, ib: _member_sums(xb, ib, k), x, ids)
     means = sums / jnp.maximum(counts, 1.0)[:, None]
     return means, counts
 
@@ -40,10 +62,9 @@ def kmeans(key: jax.Array, x: jax.Array, k: int, iters: int = 25) -> jax.Array:
 
     def step(carry, _):
         cents = carry
-        ids = assign(x, cents)
+        ids, d = map_rows(lambda xb: _fit(xb, cents), x)
         means, counts = _update(x, ids, k)
         # re-seed empties at the worst-fit point
-        d = jnp.sum((x - cents[ids]) ** 2, axis=-1)
         worst = x[jnp.argmax(d)]
         cents = jnp.where((counts > 0)[:, None], means, worst[None, :])
         return cents, None

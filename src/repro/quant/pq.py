@@ -8,6 +8,9 @@ distances; scoring a code is M table lookups + adds.
 These are the "fast memory" structures of Fig. 3: codes (N, M) uint8 and
 codebooks (M, K, D/M) stay hot; FaTRQ streams only residual codes from far
 memory.
+
+Training and encoding walk the subspaces one at a time (``lax.map``) and
+decoding walks row chunks, so no (M, N, ·) temporary is ever built.
 """
 
 from __future__ import annotations
@@ -18,7 +21,8 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from repro.quant.kmeans import assign, kmeans
+from repro.core.rows import map_rows
+from repro.quant.kmeans import assign_rows, kmeans
 
 
 @functools.partial(jax.tree_util.register_dataclass, data_fields=("codebooks",),
@@ -44,32 +48,42 @@ class PQCodebook:
         return self.m * self.ds
 
 
+def _subspace(x: jax.Array, j, ds: int) -> jax.Array:
+    """Columns [j·ds, (j+1)·ds) of x (N, D) — subspace j."""
+    return jax.lax.dynamic_slice_in_dim(x, j * ds, ds, axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("m", "k", "iters"))
 def train(key: jax.Array, x: jax.Array, m: int, k: int = 256,
           iters: int = 20) -> PQCodebook:
     """Train M independent sub-codebooks on x (N, D)."""
     n, d = x.shape
     assert d % m == 0, f"D={d} not divisible by M={m}"
-    subs = x.reshape(n, m, d // m).transpose(1, 0, 2)       # (M, N, Ds)
     keys = jax.random.split(key, m)
-    books = jax.vmap(lambda kk, xs: kmeans(kk, xs, k, iters))(keys, subs)
+    books = jax.lax.map(
+        lambda a: kmeans(a[1], _subspace(x, a[0], d // m), k, iters),
+        (jnp.arange(m), keys))                                # (M, K, Ds)
     return PQCodebook(codebooks=books)
 
 
 @jax.jit
 def encode(cb: PQCodebook, x: jax.Array) -> jax.Array:
     """x (N, D) → codes (N, M) uint8 (K ≤ 256)."""
-    n, d = x.shape
-    subs = x.reshape(n, cb.m, cb.ds).transpose(1, 0, 2)
-    ids = jax.vmap(assign)(subs, cb.codebooks)               # (M, N)
+    ids = jax.lax.map(
+        lambda j: assign_rows(_subspace(x, j, cb.ds), cb.codebooks[j]),
+        jnp.arange(cb.m))                                     # (M, N)
     return ids.T.astype(jnp.uint8)
 
 
+@jax.jit
 def decode(cb: PQCodebook, codes: jax.Array) -> jax.Array:
     """codes (N, M) → reconstruction x_c (N, D)."""
-    gathered = jax.vmap(lambda book, ids: book[ids], in_axes=(0, 1))(
-        cb.codebooks, codes.astype(jnp.int32))               # (M, N, Ds)
-    n = codes.shape[0]
-    return gathered.transpose(1, 0, 2).reshape(n, cb.m * cb.ds)
+    def rows(c):
+        gathered = jax.vmap(lambda book, ids: book[ids], in_axes=(0, 1))(
+            cb.codebooks, c.astype(jnp.int32))               # (M, B, Ds)
+        return gathered.transpose(1, 0, 2).reshape(c.shape[0], cb.dim)
+
+    return map_rows(rows, codes)
 
 
 def adc_table(cb: PQCodebook, q: jax.Array) -> jax.Array:

@@ -1,6 +1,8 @@
 """Gradient-compression tests: error-feedback telescoping + multi-device
 compressed psum (subprocess with 8 fake devices)."""
 
+import os
+import pathlib
 import subprocess
 import sys
 
@@ -48,6 +50,15 @@ class TestQuantize:
             wire_bytes(grads, compressed=False)
 
 
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+
+
+def _child_env() -> dict:
+    """The test's own environment, with the repo's ``src`` on the path."""
+    path = [str(ROOT / "src"), os.environ.get("PYTHONPATH", "")]
+    return dict(os.environ, PYTHONPATH=os.pathsep.join(p for p in path if p))
+
+
 def test_compressed_psum_multidevice():
     """Run the shard_map int8 psum on 8 fake devices in a subprocess."""
     code = """
@@ -58,13 +69,12 @@ from functools import partial
 from jax.sharding import PartitionSpec as P
 from repro.train.compression import compressed_psum
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 err0 = jnp.zeros((8, 64))
 
-from repro.compat import shard_map
-
-@partial(shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
+@partial(jax.shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
          out_specs=(P("data"), P("data")))
 def f(xs, es):
     tot, err = compressed_psum(xs[0], "data", es[0])
@@ -81,8 +91,7 @@ print("OK rel=%.4f" % rel)
     try:
         r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                            text=True,
-                           env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-                           cwd="/root/repo", timeout=600)
+                           env=_child_env(), cwd=ROOT, timeout=600)
     except subprocess.TimeoutExpired:
         # NB: this can also mask a deadlocked collective; on CI-class
         # machines the run takes well under the limit, so a skip there

@@ -1,5 +1,7 @@
 """flash_decode numerics vs reference attention on 8 fake devices."""
 
+import os
+import pathlib
 import subprocess
 import sys
 
@@ -41,10 +43,13 @@ print("OK")
 """
 
 
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+
+
 def test_flash_decode_matches_reference():
+    path = [str(ROOT / "src"), os.environ.get("PYTHONPATH", "")]
+    env = dict(os.environ, PYTHONPATH=os.pathsep.join(p for p in path if p))
     r = subprocess.run([sys.executable, "-c", CODE], capture_output=True,
-                       text=True,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-                       cwd="/root/repo", timeout=600)
+                       text=True, env=env, cwd=ROOT, timeout=600)
     assert r.returncode == 0, r.stderr[-3000:]
     assert "OK" in r.stdout

@@ -286,11 +286,11 @@ class TestFusedRefineKernel:
 
     def test_interpret_auto_detection(self):
         """Direct kernel calls (no interpret kwarg) must auto-detect the
-        backend instead of silently interpreting on TPU."""
+        backend when traced instead of silently interpreting on TPU."""
         from repro.kernels import ternary_refine as tr
-        assert tr._resolve_interpret(None) == (not tr._ON_TPU)
-        assert tr._resolve_interpret(True) is True
-        assert tr._resolve_interpret(False) is False
+        assert tr.resolve_interpret(None) == (jax.default_backend() != "tpu")
+        assert tr.resolve_interpret(True) is True
+        assert tr.resolve_interpret(False) is False
         args = _setup_refine(64, 20, seed=9)
         packed, q, d0, delta_sq, cross, norm, rho, w, bias = args
         q_planes = ref.make_query_planes(q, packed.shape[1])

@@ -145,3 +145,26 @@ class TestModelFlops:
     def test_moe_uses_active_params(self):
         cfg = ARCHS["mixtral-8x22b"]
         assert cfg.active_params_count() < 0.45 * cfg.params_count()
+
+
+@pytest.mark.parametrize("env_dir", [None, "/shared/jax-cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """``$JAX_COMPILATION_CACHE_DIR`` wins and nothing else is set;
+    otherwise the cache sits at the fixed ``<repo>/.jax_cache``."""
+    from repro.launch import compile_cache
+    old = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        path = compile_cache.enable()
+        if env_dir is None:
+            assert path == str(compile_cache.REPO_ROOT / ".jax_cache")
+            assert (compile_cache.REPO_ROOT / "chip_smoke.py").exists()
+            assert jax.config.jax_compilation_cache_dir == path
+        else:
+            assert path == env_dir
+            assert jax.config.jax_compilation_cache_dir == old
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)

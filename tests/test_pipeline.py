@@ -138,3 +138,45 @@ class TestCostModel:
         c.record("s", Tier.SSD, 10, 100)   # 100 B reads cost 4 KiB each
         t = [v for k, v in c.ledger.items() if k.endswith("ssd")][0]
         assert t.bytes == 10 * 4096
+
+
+class TestChunkedBuild:
+    """The build's row chunking bounds device temporaries at deployment
+    scale; it must not change the index.  One chunk covering N against
+    several chunks with a short tail (2000 = 3·600 + 200)."""
+
+    @pytest.fixture(scope="class")
+    def rows(self):
+        return make_dataset(jax.random.PRNGKey(3), n=2000, d=40,
+                            n_queries=4, k_gt=10, clusters=8).x
+
+    @pytest.mark.parametrize("part", ["pq_codes", "trq_packed",
+                                      "ivf_lists"])
+    def test_one_chunk_equals_several(self, rows, part, monkeypatch):
+        from repro.core import rows as rows_mod
+        from repro.core import trq as trq_mod
+        from repro.quant import pq
+
+        def built(chunk):
+            # ROW_CHUNK is read when the build programs are traced
+            monkeypatch.setattr(rows_mod, "ROW_CHUNK", chunk)
+            jax.clear_caches()
+            if part == "ivf_lists":
+                idx = ivf.build(jax.random.PRNGKey(2), rows, nlist=16)
+                return [idx.lists, idx.list_len, idx.centroids]
+            cb = pq.train(jax.random.PRNGKey(4), rows, m=4, k=32, iters=6)
+            codes = pq.encode(cb, rows)
+            if part == "pq_codes":
+                return [codes, cb.codebooks]
+            x_c = pq.decode(cb, codes)
+            trq, _ = trq_mod.encode_database(rows, x_c, num_levels=2)
+            return [lv.packed for lv in trq.levels] + [trq.scalars.norm]
+
+        one, several = built(1 << 20), built(600)
+        jax.clear_caches()
+        for a, b in zip(one[:-1], several[:-1]):
+            assert jnp.array_equal(a, b)
+        # float sums over rows may differ in their last bits only
+        np.testing.assert_allclose(np.asarray(one[-1]),
+                                   np.asarray(several[-1]),
+                                   rtol=1e-6, atol=1e-6)
