@@ -59,22 +59,17 @@ def main():
           f"{ {k: t.accesses for k, t in retriever.total_cost.ledger.items()} }")
     print(f"  engine stats: {engine.stats}")
 
-    # --- per-stage latency breakdown from the trace the retrieval just
-    # produced: wall time (this host, measured) next to the QueryCost
-    # Table-I modeled time that the perf gate pins, and their ratio.
-    print("per-stage latency breakdown (traced):")
-    for stage in ("front", "refine", "rerank"):
-        spans = tracer.by_name(stage)
-        if not spans:
-            continue
-        wall_ms = sum(s.wall_end_s - s.wall_start_s for s in spans) * 1e3
-        model = [s.attrs["model_s"] for s in spans if "model_s" in s.attrs]
-        model_ms = sum(model) * 1e3 if model else float("nan")
-        drift = wall_ms / model_ms if model_ms else float("nan")
-        print(f"  {stage:>7}: wall {wall_ms:8.3f} ms | "
-              f"modeled {model_ms:8.3f} ms | wall/model {drift:8.1f}x "
-              f"({len(spans)} span(s))")
-
+    # --- per-stage ledger bytes from the spans the retrieval just traced:
+    # the folded Table-I ledger rides on each ``execute`` span (the
+    # device's own time per layer comes from a profiler trace, not here)
+    print("per-stage ledger bytes (traced):")
+    for sp in tracer.by_name("execute"):
+        per_stage: dict[str, int] = {}
+        for key, (_, nbytes) in sp.attrs.get("ledger", {}).items():
+            stage = key.split(":", 1)[0]
+            per_stage[stage] = per_stage.get(stage, 0) + nbytes
+        for stage, nbytes in per_stage.items():
+            print(f"  {stage:>8}: {nbytes:12,d} B")
 
 if __name__ == "__main__":
     main()

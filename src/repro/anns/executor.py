@@ -30,7 +30,7 @@ from repro.anns.stages import (Counters, FrontStage, RefineBackend,
 from repro.index import graph as graph_mod
 from repro.memory import QueryCost, Tier
 from repro.memory.placement import TIER_COLD, TIER_HOT
-from repro.obs import metrics, trace
+from repro.obs import trace
 
 # import-time snapshots of the capability registry, kept as module
 # constants for pre-registry callers (stages.py has registered the
@@ -42,11 +42,6 @@ REFINE_BACKENDS = registry.backend_names()
 
 # measured scale of ADC + ternary adds per candidate (see benchmarks)
 _COMPUTE_S_PER_CAND = 1e-7
-
-# wall/modeled drift ratio buckets: <1 means the tier model over-charges,
-# large values are expected on the interpreted CPU backend
-_DRIFT_BUCKETS = (0.25, 0.5, 1.0, 2.0, 5.0, 10.0, 100.0, 1_000.0,
-                  10_000.0, 100_000.0)
 
 
 def _accumulate(total: Counters, new: Counters) -> Counters:
@@ -108,8 +103,10 @@ def _collect(counters: Counters) -> dict:
     """The single device→host transfer of a search call.  Scalar counters
     come back as Python ints; vector counters (the tiered layout's
     per-list ``list_heat`` histogram) as numpy arrays."""
+    with trace.span("wait", track="query"):
+        vals = jax.device_get(list(counters.values()))
     out = {}
-    for n, v in zip(counters, jax.device_get(list(counters.values()))):
+    for n, v in zip(counters, vals):
         a = np.asarray(v)
         out[n] = int(a) if a.ndim == 0 else a
     return out
@@ -151,21 +148,11 @@ class SearchExecutor:
     def _chunks(self, queries: jax.Array):
         return iter_chunks(queries, self.micro_batch)
 
-    def _refine_rerank(self, chunk: jax.Array, cand, *, k: int, budget: int,
-                       front_span=None
+    def _refine_rerank(self, chunk: jax.Array, cand, *, k: int, budget: int
                        ) -> tuple[jax.Array, jax.Array, Counters]:
         """Refine + SSD rerank over a front-stage result: the shared tail
-        of ``execute`` and ``run_finish``.
-
-        When tracing is active the refine/rerank spans block on their
-        device results before closing (so wall times cover the device
-        work, not just the async enqueue) and this chunk's counters are
-        folded a second time to attach modeled per-stage seconds + the
-        wall/model drift ratio to the spans (``_attach_model``).  Both
-        are gated on ``trace.active()`` — disabled runs keep the async
-        single-transfer path and bit-identical results."""
+        of ``execute`` and ``run_finish``."""
         cfg = self.index.config
-        tr = trace.active()
         hot = cold = None
         rcand = cand
         if cand.tier is not None:
@@ -180,13 +167,10 @@ class SearchExecutor:
             rcand = cand._replace(valid=cand.valid & ~hot,
                                   d0=jnp.where(hot, jnp.inf, cand.d0),
                                   is_delta=cold, tier=None)
-        with trace.span("refine", track="query",
-                        backend=self.backend.name) as sp_refine:
+        with trace.span("refine", track="query", backend=self.backend.name):
             refined = self.backend.refine(chunk, rcand, self.index.trq,
                                           k=k, bound=cfg.bound, z=cfg.z)
-            if tr is not None:
-                jax.block_until_ready(refined.est)
-        with trace.span("rerank", track="query", budget=budget) as sp_rerank:
+        with trace.span("rerank", track="query", budget=budget):
             if hot is not None:
                 d_hot = stages_mod._score_hot(self.index.x, chunk, cand.ids,
                                               hot)
@@ -199,68 +183,10 @@ class SearchExecutor:
                 topk, topk_d, n_ssd = stages_mod._rerank_survivors(
                     self.index.x, chunk, cand.ids, refined.est,
                     refined.alive, k=k, budget=budget)
-            if tr is not None:
-                jax.block_until_ready(topk)
         counters = dict(cand.counters)
         _accumulate(counters, refined.counters)
         _accumulate(counters, {"ssd_fetch": n_ssd})
-        if tr is not None:
-            self._attach_model(tr, {"front": front_span, "refine": sp_refine,
-                                    "rerank": sp_rerank}, counters)
         return topk, topk_d, counters
-
-    def _attach_model(self, tr, spans: dict, counters: Counters) -> None:
-        """Tracing-only: fold THIS chunk's counters into a throwaway
-        ledger and attach per-stage modeled seconds (front → HBM,
-        refine incl. handoff/delta → CXL, rerank → SSD) plus the
-        measured-wall / modeled drift ratio to the stage spans; observe
-        the drift into the ``fatrq_model_drift_ratio{stage=...}``
-        histogram.  Also emits one ``refine.l{lv}`` event per TRQ level
-        with that level's entering/delta candidate counts and modeled
-        CXL time — the per-level view the folded ledger flattens away.
-
-        Costs one extra device→host transfer per chunk; only runs when
-        a tracer is active."""
-        counts = _collect(counters)
-        cost = fold_counts(counts, cost=None, config=self.index.config,
-                           layout=self.index.layout,
-                           front_fold=self.front.fold_cost)
-        model_s = {"front": cost.tier_seconds(Tier.HBM),
-                   "refine": cost.tier_seconds(Tier.CXL),
-                   "rerank": cost.tier_seconds(Tier.SSD)}
-        drift = metrics.active().histogram(
-            "fatrq_model_drift_ratio",
-            "measured wall seconds / QueryCost-modeled seconds per stage",
-            labelnames=("stage",), buckets=_DRIFT_BUCKETS)
-        for stage, handle in spans.items():
-            if handle is None or handle.span is None:
-                continue
-            m = model_s[stage]
-            handle.set_attr("model_s", m)
-            wall = handle.span.wall_s
-            if wall is not None and m > 0:
-                ratio = wall / m
-                handle.set_attr("wall_model_drift", ratio)
-                drift.labels(stage=stage).observe(ratio)
-        # per-level refine annotation, mirroring fold_counts' level walk:
-        # level 0 streams every candidate, level ℓ ≥ 1 only survivors
-        sp_refine = spans.get("refine")
-        parent = (sp_refine.span.sid
-                  if sp_refine is not None and sp_refine.span is not None
-                  else None)
-        cxl = cost.model[Tier.CXL]
-        far = self.index.layout.far_bytes
-        n_alive = counts.get("refine_alive", 0)
-        for lv in range(self.index.config.trq_levels):
-            if lv == 0:
-                n_lv = counts.get("front_cand", 0)
-                n_lv_delta = counts.get("delta_cand", 0)
-            else:
-                n_lv = counts.get(f"refine_alive_l{lv}", n_alive)
-                n_lv_delta = counts.get(f"refine_alive_l{lv}_delta", 0)
-            tr.event(f"refine.l{lv}", track="query", parent=parent,
-                     level=lv, entering=int(n_lv), delta=int(n_lv_delta),
-                     model_s=cxl.seconds(n_lv, n_lv * far))
 
     def execute(self, queries: jax.Array, *, k: int | None = None,
                 cost: QueryCost | None = None, pad: bool = False
@@ -292,12 +218,10 @@ class SearchExecutor:
                 else:
                     qvalid = None
                 with trace.span("front", track="query",
-                                stage=self.front.name, n=n) as sp_front:
+                                stage=self.front.name, n=n):
                     cand = self.front.candidates(chunk, qvalid=qvalid)
-                    if tr is not None:
-                        jax.block_until_ready(cand.d0)
                 topk, topk_d, cnt = self._refine_rerank(
-                    chunk, cand, k=k, budget=budget, front_span=sp_front)
+                    chunk, cand, k=k, budget=budget)
                 if topk.shape[0] != n:             # drop padded rows
                     topk, topk_d = topk[:n], topk_d[:n]
                 topk_parts.append(topk)
@@ -317,37 +241,10 @@ class SearchExecutor:
         generation is enqueued on the device and returned as a
         ``Candidates`` handle.  The serving engine issues this for batch
         N+1 while batch N's ``run_finish`` (refine + rerank) drains —
-        JAX's async dispatch overlaps the two stages on device.  With a
-        tracer active the span blocks on the result (observer effect:
-        traced wall times are honest per-stage, at the price of the
-        device-side overlap; the virtual-clock pipeline model is
-        unaffected)."""
-        tr = trace.active()
+        JAX's async dispatch overlaps the two stages on device."""
         with trace.span("front", track="query", stage=self.front.name,
-                        n=int(chunk.shape[0]), split=True) as sp:
-            cand = self.front.candidates(chunk, qvalid=qvalid)
-            if tr is not None:
-                jax.block_until_ready(cand.d0)
-        if tr is not None:
-            # split dispatch never reaches _attach_model with this span
-            # (run_finish folds a different chunk's handle), so attribute
-            # the front model time here from the front counters alone
-            counts = _collect(dict(cand.counters))
-            cost = QueryCost()
-            self.front.fold_cost(cost, counts, self.index.layout)
-            m = cost.tier_seconds(Tier.HBM)
-            sp.set_attr("model_s", m)
-            if sp.span.wall_s is not None and m > 0:
-                ratio = sp.span.wall_s / m
-                sp.set_attr("wall_model_drift", ratio)
-                metrics.active().histogram(
-                    "fatrq_model_drift_ratio",
-                    "measured wall seconds / QueryCost-modeled seconds "
-                    "per stage",
-                    labelnames=("stage",),
-                    buckets=_DRIFT_BUCKETS).labels(stage="front") \
-                    .observe(ratio)
-        return cand
+                        n=int(chunk.shape[0]), split=True):
+            return self.front.candidates(chunk, qvalid=qvalid)
 
     def run_finish(self, chunk: jax.Array, cand, *, k: int | None = None,
                    cost: QueryCost | None = None
@@ -399,13 +296,9 @@ class SearchExecutor:
                 with trace.span("front", track="query",
                                 stage=self.front.name, n=n):
                     cand = self.front.candidates(chunk, qvalid=qvalid)
-                    if tr is not None:
-                        jax.block_until_ready(cand.d0)
                 with trace.span("rerank", track="query", baseline=True):
                     topk, topk_d, n_valid = stages_mod._rerank_all(
                         self.index.x, chunk, cand.ids, cand.valid, k=k)
-                    if tr is not None:
-                        jax.block_until_ready(topk)
                 if topk.shape[0] != n:             # drop padded rows
                     topk, topk_d = topk[:n], topk_d[:n]
                 topk_parts.append(topk)
@@ -437,15 +330,36 @@ class SearchExecutor:
         The tiered layout's per-list access histogram rides the same
         transfer and feeds the index's heat tracker here — heat tracking
         costs no extra device round-trips."""
-        counts = _collect(counters)
-        heat = counts.pop("list_heat", None)
-        if heat is not None:
-            observe = getattr(self.index, "observe_heat", None)
-            if observe is not None:
-                observe(heat)
-        return fold_counts(counts, cost=cost, config=self.index.config,
-                           layout=self.index.layout,
-                           front_fold=self.front.fold_cost)
+        with trace.span("fold", track="query"):
+            counts = _collect(counters)
+            heat = counts.pop("list_heat", None)
+            if heat is not None:
+                observe = getattr(self.index, "observe_heat", None)
+                if observe is not None:
+                    observe(heat)
+            if trace.active() is not None:
+                self._level_events(counts)
+            return fold_counts(counts, cost=cost, config=self.index.config,
+                               layout=self.index.layout,
+                               front_fold=self.front.fold_cost)
+
+    def _level_events(self, counts: dict) -> None:
+        """One ``refine.l{ℓ}`` event per TRQ level with the candidates
+        entering it and their delta-page share, from the counters the
+        fold already transferred: the per-level view (the paper's early
+        exit) that the folded ledger flattens away.  Level 0 streams
+        every candidate, level ℓ ≥ 1 only survivors, as in
+        ``fold_counts``."""
+        n_alive = counts.get("refine_alive", 0)
+        for lv in range(self.index.config.trq_levels):
+            if lv == 0:
+                n_lv = counts.get("front_cand", 0)
+                n_lv_delta = counts.get("delta_cand", 0)
+            else:
+                n_lv = counts.get(f"refine_alive_l{lv}", n_alive)
+                n_lv_delta = counts.get(f"refine_alive_l{lv}_delta", 0)
+            trace.event(f"refine.l{lv}", track="query", level=lv,
+                        entering=int(n_lv), delta=int(n_lv_delta))
 
 
 def _attach_ledger(handle, cost: QueryCost) -> None:

@@ -83,7 +83,7 @@ from repro.core.decomposition import RecordScalars
 from repro.core.estimator import pooled_k_smallest
 from repro.core.trq import TRQCodes, TRQLevel
 from repro.index import graph as graph_mod
-from repro.memory import QueryCost, RecordLayout, Tier
+from repro.memory import QueryCost, RecordLayout
 from repro.obs import trace
 from repro.quant import pq as pq_mod
 
@@ -353,7 +353,7 @@ def _ivf_shard_front(queries, rep, fdb, codebook, pq_codes, *,
     if qvalid is not None:
         valid = valid & qvalid[:, None]
     ids = jnp.maximum(ids_l.reshape(nq, pl * cap), 0)
-    d0 = adc_score(codebook, pq_codes[ids], queries, valid)
+    d0 = adc_score(codebook, pq_codes, ids, queries, valid)
     return Candidates(ids=ids, valid=valid, d0=d0,
                       counters={"front_cand": jnp.sum(valid)})
 
@@ -427,7 +427,7 @@ def _graph_shard_front(queries, rep, fdb, codebook, pq_codes, *,
     if qvalid is not None:
         valid = valid & qvalid[:, None]
     ids_local = jnp.maximum(lfin, 0)
-    d0 = adc_score(codebook, pq_codes[ids_local], queries, valid)
+    d0 = adc_score(codebook, pq_codes, ids_local, queries, valid)
     return Candidates(ids=ids_local, valid=valid, d0=d0,
                       counters={"front_cand": jnp.sum(valid),
                                 "front_hops": hops * degree})
@@ -619,22 +619,16 @@ class ShardedExecutor:
                 topk_parts.append(topk)
                 dist_parts.append(topk_d)
                 _accumulate(counters, cnt)
-            if tr is not None:
-                jax.block_until_ready(topk_parts[-1])
 
             merged = self._fold(counters)
             if tr is not None:
                 # the shard_map body fuses front/refine/rerank into one
-                # compiled region — no host-side stage boundaries exist to
-                # time, so emit model-attributed stage events instead
-                # (fused=True) to keep the span↔ledger coverage invariant
-                # on the sharded layout.
+                # compiled region — no host-side stage boundaries exist, so
+                # emit one event per stage instead (fused=True) to keep the
+                # span↔ledger coverage invariant on the sharded layout.
                 sid = sp_ex.span.sid
-                for stage, tier in (("front", Tier.HBM),
-                                    ("refine", Tier.CXL),
-                                    ("rerank", Tier.SSD)):
-                    tr.event(stage, track="query", parent=sid, fused=True,
-                             model_s=merged.tier_seconds(tier))
+                for stage in ("front", "refine", "rerank"):
+                    tr.event(stage, track="query", parent=sid, fused=True)
                 _attach_ledger(sp_ex, merged)
             if cost is not None:
                 merged = cost.merge(merged)
@@ -657,7 +651,8 @@ class ShardedExecutor:
         si = self.sharded
         front_fold = registry.sharded_front(si.front).fold
         names = list(counters)
-        vals = jax.device_get([counters[n] for n in names])
+        with trace.span("wait", track="query"):
+            vals = jax.device_get([counters[n] for n in names])
 
         shard_costs = []
         for s in range(si.n_shards):

@@ -140,6 +140,13 @@ def fold_ivf_front_cost(cost: QueryCost, counts: dict[str, int],
     cost.record("coarse", Tier.HBM, counts["front_cand"], layout.fast_bytes)
 
 
+# Device work of each layer runs under a stable ``jax.named_scope``
+# (``fatrq.front.probe``, ``fatrq.front.adc``, ``fatrq.refine.gather``,
+# ``fatrq.refine.kernel``, ``fatrq.rerank``).  A scope changes only the op
+# names in the HLO metadata, which a profile carries as each device op's
+# path, so the layer of an op survives any change of jit boundaries.
+
+
 def rank_centroid_lists(centroids: jax.Array, queries: jax.Array, *,
                         nprobe: int) -> tuple[jax.Array, jax.Array]:
     """Squared-L2 centroid ranking → (distances (Q, nlist), global
@@ -149,18 +156,23 @@ def rank_centroid_lists(centroids: jax.Array, queries: jax.Array, *,
     (``anns.sharding``) — the sharded path's bit-identical guarantee
     depends on both selecting the same probe set.
     """
-    d = jnp.sum((queries[:, None, :] - centroids[None]) ** 2, axis=-1)
-    _, top_lists = jax.lax.top_k(-d, nprobe)
+    with jax.named_scope("fatrq.front.probe"):
+        d = jnp.sum((queries[:, None, :] - centroids[None]) ** 2, axis=-1)
+        _, top_lists = jax.lax.top_k(-d, nprobe)
     return d, top_lists
 
 
-def adc_score(codebook: pq_mod.PQCodebook, codes: jax.Array,
-              queries: jax.Array, valid: jax.Array) -> jax.Array:
-    """Batched PQ-ADC scoring of per-query gathered codes (Q, C, M),
-    +inf outside ``valid``.  Shared with the sharded front likewise."""
-    tables = jax.vmap(lambda q: pq_mod.adc_table(codebook, q))(queries)
-    d0 = jax.vmap(pq_mod.adc_distances)(tables, codes)
-    return jnp.where(valid, d0, jnp.inf)
+def adc_score(codebook: pq_mod.PQCodebook, pq_codes: jax.Array,
+              ids: jax.Array, queries: jax.Array,
+              valid: jax.Array) -> jax.Array:
+    """Batched PQ-ADC scoring of per-query candidates ``ids`` (Q, C):
+    gathers their PQ codes (Q, C, M) and scores them, +inf outside
+    ``valid``.  Shared by every front, sharded or not."""
+    with jax.named_scope("fatrq.front.adc"):
+        codes = pq_codes[ids]
+        tables = jax.vmap(lambda q: pq_mod.adc_table(codebook, q))(queries)
+        d0 = jax.vmap(pq_mod.adc_distances)(tables, codes)
+        return jnp.where(valid, d0, jnp.inf)
 
 
 @partial(jax.jit, static_argnames=("nprobe",))
@@ -168,12 +180,13 @@ def _ivf_candidates(ivf: ivf_mod.IVFIndex, codebook, pq_codes, queries,
                     qvalid, *, nprobe: int):
     _, top_lists = rank_centroid_lists(ivf.centroids, queries,
                                        nprobe=nprobe)
-    ids = ivf.lists[top_lists].reshape(queries.shape[0], -1)  # (Q, nprobe·cap)
-    valid = ids >= 0
+    with jax.named_scope("fatrq.front.probe"):
+        ids = ivf.lists[top_lists].reshape(queries.shape[0], -1)
+    valid = ids >= 0                       # ids: (Q, nprobe·cap)
     if qvalid is not None:                 # padded rows: no candidates
         valid = valid & qvalid[:, None]
     safe = jnp.maximum(ids, 0)
-    d0 = adc_score(codebook, pq_codes[safe], queries, valid)
+    d0 = adc_score(codebook, pq_codes, safe, queries, valid)
     return safe, valid, d0, jnp.sum(valid)
 
 
@@ -209,9 +222,7 @@ def _graph_candidates(neighbors, x_score, codebook, pq_codes, queries,
         queries)                                              # (Q, beam)
     valid = jnp.ones(ids.shape, bool) if qvalid is None \
         else jnp.broadcast_to(qvalid[:, None], ids.shape)
-    tables = jax.vmap(lambda q: pq_mod.adc_table(codebook, q))(queries)
-    d0 = jax.vmap(pq_mod.adc_distances)(tables, pq_codes[ids])
-    d0 = jnp.where(valid, d0, jnp.inf)
+    d0 = adc_score(codebook, pq_codes, ids, queries, valid)
     return ids, valid, d0, jnp.sum(valid)
 
 
@@ -355,19 +366,21 @@ def _pallas_refine(queries, d0, ids, valid, is_delta, trq: TRQCodes, *,
     between level segments — bit-identical masks to the on-chip form.
     """
     sc = trq.scalars
-    packed_levels = jnp.stack([lv.packed[ids] for lv in trq.levels])
-    lvl_proj = jnp.stack([lv.proj[ids] for lv in trq.levels])
-    lvl_norm = jnp.stack([lv.norm[ids] for lv in trq.levels])
-    lvl_rho = jnp.stack([lv.rho[ids] for lv in trq.levels])
-    delta_mask = jnp.zeros_like(valid) if is_delta is None else is_delta
-    args = (packed_levels, queries, d0, sc.delta_sq[ids], sc.cross[ids],
-            sc.norm[ids], sc.rho[ids], valid, delta_mask, lvl_proj,
-            lvl_norm, lvl_rho, trq.model.w, trq.model.bias,
-            trq.model.resid_std, z)
+    with jax.named_scope("fatrq.refine.gather"):
+        packed_levels = jnp.stack([lv.packed[ids] for lv in trq.levels])
+        lvl_proj = jnp.stack([lv.proj[ids] for lv in trq.levels])
+        lvl_norm = jnp.stack([lv.norm[ids] for lv in trq.levels])
+        lvl_rho = jnp.stack([lv.rho[ids] for lv in trq.levels])
+        delta_mask = jnp.zeros_like(valid) if is_delta is None else is_delta
+        args = (packed_levels, queries, d0, sc.delta_sq[ids], sc.cross[ids],
+                sc.norm[ids], sc.rho[ids], valid, delta_mask, lvl_proj,
+                lvl_norm, lvl_rho, trq.model.w, trq.model.bias,
+                trq.model.resid_std, z)
 
     if axis_name is None:
-        est, alive, counts = kernel_ops.fused_refine_scores_batch(
-            *args, k=k, bound=bound, block_c=block_c)
+        with jax.named_scope("fatrq.refine.kernel"):
+            est, alive, counts = kernel_ops.fused_refine_scores_batch(
+                *args, k=k, bound=bound, block_c=block_c)
         nl = trq.num_levels
         counters: Counters = {"refine_alive": jnp.sum(counts[:, nl - 1])}
         for lv in range(1, nl):
@@ -377,8 +390,9 @@ def _pallas_refine(queries, d0, ids, valid, is_delta, trq: TRQCodes, *,
                     counts[:, nl + lv - 1])
         return est, alive, counters
 
-    est, lo, hi = kernel_ops.fused_refine_bounds_batch(
-        *args, bound=bound, block_c=block_c)
+    with jax.named_scope("fatrq.refine.kernel"):
+        est, lo, hi = kernel_ops.fused_refine_bounds_batch(
+            *args, bound=bound, block_c=block_c)
     alive = valid
     level_alive = []
     for lv in range(trq.num_levels):
@@ -422,15 +436,16 @@ def _rerank_survivors(x, queries, ids, est, alive, *, k: int, budget: int):
     vectors, exact L2, top-k.  Returns (topk_ids, topk_dists, n_ssd) —
     distances are the exact squared L2 of each returned id (+inf on padded
     slots when fewer than k candidates survived)."""
-    est_m = jnp.where(alive, est, jnp.inf)
-    _, order = jax.lax.top_k(-est_m, budget)                  # (Q, budget)
-    fetch_ids = jnp.take_along_axis(ids, order, axis=1)
-    fetch_alive = jnp.take_along_axis(alive, order, axis=1)
-    d = jnp.sum((x[fetch_ids] - queries[:, None, :]) ** 2, axis=-1)
-    d = jnp.where(fetch_alive, d, jnp.inf)
-    neg_d, best = jax.lax.top_k(-d, k)
-    topk = jnp.take_along_axis(fetch_ids, best, axis=1)
-    return topk, -neg_d, jnp.sum(fetch_alive)
+    with jax.named_scope("fatrq.rerank"):
+        est_m = jnp.where(alive, est, jnp.inf)
+        _, order = jax.lax.top_k(-est_m, budget)              # (Q, budget)
+        fetch_ids = jnp.take_along_axis(ids, order, axis=1)
+        fetch_alive = jnp.take_along_axis(alive, order, axis=1)
+        d = jnp.sum((x[fetch_ids] - queries[:, None, :]) ** 2, axis=-1)
+        d = jnp.where(fetch_alive, d, jnp.inf)
+        neg_d, best = jax.lax.top_k(-d, k)
+        topk = jnp.take_along_axis(fetch_ids, best, axis=1)
+        return topk, -neg_d, jnp.sum(fetch_alive)
 
 
 @jax.jit
@@ -450,17 +465,18 @@ def _rerank_survivors_tiered(x, queries, ids, est, alive, hot, *, k: int,
     distances, but hot candidates' full vectors are already HBM-resident —
     their fetches must not bill to the SSD rerank counter.  Returns
     (topk_ids, topk_dists, n_ssd, n_hot_fetch)."""
-    est_m = jnp.where(alive, est, jnp.inf)
-    _, order = jax.lax.top_k(-est_m, budget)
-    fetch_ids = jnp.take_along_axis(ids, order, axis=1)
-    fetch_alive = jnp.take_along_axis(alive, order, axis=1)
-    fetch_hot = jnp.take_along_axis(hot, order, axis=1) & fetch_alive
-    d = jnp.sum((x[fetch_ids] - queries[:, None, :]) ** 2, axis=-1)
-    d = jnp.where(fetch_alive, d, jnp.inf)
-    neg_d, best = jax.lax.top_k(-d, k)
-    topk = jnp.take_along_axis(fetch_ids, best, axis=1)
-    return (topk, -neg_d, jnp.sum(fetch_alive & ~fetch_hot),
-            jnp.sum(fetch_hot))
+    with jax.named_scope("fatrq.rerank"):
+        est_m = jnp.where(alive, est, jnp.inf)
+        _, order = jax.lax.top_k(-est_m, budget)
+        fetch_ids = jnp.take_along_axis(ids, order, axis=1)
+        fetch_alive = jnp.take_along_axis(alive, order, axis=1)
+        fetch_hot = jnp.take_along_axis(hot, order, axis=1) & fetch_alive
+        d = jnp.sum((x[fetch_ids] - queries[:, None, :]) ** 2, axis=-1)
+        d = jnp.where(fetch_alive, d, jnp.inf)
+        neg_d, best = jax.lax.top_k(-d, k)
+        topk = jnp.take_along_axis(fetch_ids, best, axis=1)
+        return (topk, -neg_d, jnp.sum(fetch_alive & ~fetch_hot),
+                jnp.sum(fetch_hot))
 
 
 @partial(jax.jit, static_argnames=("k",))

@@ -125,7 +125,7 @@ def _streaming_candidates(centroids, codebook, pq_codes, base_lists,
     valid = (ids >= 0) & alive[safe]                          # tombstone mask
     if qvalid is not None:                 # padded rows: no candidates
         valid = valid & qvalid[:, None]
-    d0 = adc_score(codebook, pq_codes[safe], queries, valid)
+    d0 = adc_score(codebook, pq_codes, safe, queries, valid)
     is_delta = jnp.broadcast_to(
         jnp.arange(ids.shape[1])[None, :] >= ids_b.shape[1], ids.shape)
     return (safe, valid, d0, is_delta, jnp.sum(valid),
@@ -179,7 +179,7 @@ def _graph_streaming_candidates(neighbors, x_score, codebook, pq_codes,
     valid = alive[ids]
     if qvalid is not None:                 # padded rows: no candidates
         valid = valid & qvalid[:, None]
-    d0 = adc_score(codebook, pq_codes[ids], queries, valid)
+    d0 = adc_score(codebook, pq_codes, ids, queries, valid)
     is_delta = ids >= n_base
     return (ids, valid, d0, is_delta, jnp.sum(valid),
             jnp.sum(valid & is_delta))

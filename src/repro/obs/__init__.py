@@ -1,28 +1,23 @@
-"""Observability subsystem: query-lifecycle tracing, process-local
-metrics, and exporters (JSONL spans, Chrome-trace JSON, Prometheus text).
+"""Observability subsystem: query-lifecycle spans, process-local metrics,
+and exporters (JSONL spans, Chrome-trace JSON, Prometheus text).
 
-The paper's whole argument is a latency budget — far-memory residual
-reads and early-exit pruning dominate query time — so this package makes
-the real per-stage breakdown visible next to the modeled one:
-
-* ``trace``   — hierarchical spans with a context-var trace context,
-  wall-clock + virtual-clock dual timestamps, per-span attributes.
-  Disabled by default: every instrumentation site goes through
-  ``trace.span(...)``, which is a single context-var read returning a
-  shared no-op handle when no tracer is active (zero-cost fast path —
-  no jit-visible work either way, pinned in ``tests/test_obs.py``).
+* ``trace``   — one call site, ``trace.span(...)``, two sinks.  While a
+  ``jax.profiler`` trace is being collected, each span is a host
+  ``TraceAnnotation`` named ``fatrq.<name>`` on the device trace's clock;
+  the device work of each layer carries a ``jax.named_scope`` of its own
+  (``anns/stages.py``), so a profile shows which layer a device op or an
+  idle gap belongs to.  With a ``Tracer`` active, spans are also kept in
+  memory with wall and virtual-clock timestamps and free-form
+  attributes.  No span waits for the device.  With neither sink on,
+  ``span`` is a context-var read and a profiler check returning a shared
+  no-op handle; no jit-visible work either way (pinned in
+  ``tests/test_obs.py``).
 * ``metrics`` — process-local registry of counters / gauges /
   histograms with label sets; the serving engine keeps one per engine,
   everything else uses the active (default) registry.
 * ``export``  — JSONL span dump (byte-deterministic under the virtual
   clock), Chrome-trace/Perfetto JSON rendered from virtual-clock spans,
   and Prometheus text exposition of a registry.
-
-The key derived signal is ``fatrq_model_drift_ratio{stage=...}``: every
-traced stage records both its measured wall time and its
-``QueryCost``-modeled time, so the histogram quantifies where the
-Table-I tier model diverges from reality — the feedback signal adaptive
-hot/cold placement needs.
 """
 
 from repro.obs import export, metrics, trace

@@ -13,10 +13,9 @@ Two registries matter in practice:
   streaming-index mutation counters and ad-hoc instrumentation land
   here;
 * a **per-engine registry** — ``ServingEngine`` owns one and activates
-  it (``use``) for the duration of ``run()``, so datapath metrics
-  recorded deep in the executor (e.g. ``fatrq_model_drift_ratio``)
-  aggregate with the engine's own queue-wait / occupancy / cache series
-  and export as one coherent scrape.
+  it (``use``) while it runs, so any series recorded through
+  ``active()`` beneath it aggregates with the engine's own queue-wait /
+  occupancy / cache series and exports as one coherent scrape.
 
 ``add_collector(fn)`` registers a callback run at export time
 (``collect()``) — used to mirror snapshot-style stats objects

@@ -1,29 +1,36 @@
-"""Hierarchical query-lifecycle spans with a context-var trace context.
+"""Query-lifecycle spans, written to two sinks from one call site.
 
-A ``Tracer`` collects ``Span`` records: name, monotonic span id, parent
-id (nesting follows the context-var current-span stack), a ``track``
-(the logical execution unit the span ran on — ``"query"`` for datapath
-stages, ``"unit:front"`` / ``"unit:refine"`` for the serving engine's
-virtual pipeline units, ``"sched"`` for scheduler events, ``"index"``
-for streaming mutations), free-form JSON-serializable attributes, and
-DUAL timestamps:
+Instrumentation sites call ``trace.span(name, **attrs)``.  Each span goes
+to whichever of two sinks is on:
 
-* **wall clock** — ``time.perf_counter()`` seconds around the host-side
-  stage call.  Instrumented stages block on their device results before
-  closing the span (the executor adds the sync only when tracing is
-  active), so the wall time covers the device work, not just the async
-  enqueue.
-* **virtual clock** — microseconds from an attached clock source
-  (``Tracer.virtual_clock``, wired to the serving engine's deterministic
-  ``VirtualClock``).  Virtual timestamps are what make traces replayable
-  and byte-identical in tests; spans created outside a virtual-clocked
-  context carry ``None``.
+* **the profiler** — while a ``jax.profiler`` trace is being collected,
+  the span enters ``jax.profiler.TraceAnnotation(f"fatrq.{name}")`` with
+  its cheap scalar attributes (``bid``, ``n``, stage names) as arguments.
+  Those host spans land on the profiler's clock, the clock of the device
+  trace, so a device op or an idle gap can be placed inside the host work
+  that issued it.  Nothing waits for the device: a span measures host
+  time (enqueue, waits for transfers, Python bookkeeping), and the
+  device's own time comes from the device trace, where the device work
+  carries stable ``jax.named_scope`` names (``fatrq.front.adc`` and the
+  rest, in ``anns/stages.py``).
+* **a ``Tracer``** — activated with ``use``.  It collects ``Span``
+  records: name, monotonic span id, parent id (nesting follows the
+  context-var current-span stack), a ``track`` (the logical unit the
+  span ran on — ``"query"`` for datapath stages, ``"unit:front"`` /
+  ``"unit:refine"`` for the serving engine's virtual pipeline units,
+  ``"sched"`` for scheduler work, ``"index"`` for streaming mutations),
+  free-form attributes (ledger dictionaries and other heavy ones stay
+  here and never reach the profiler), and dual timestamps: host
+  ``time.perf_counter()`` seconds, and microseconds from an attached
+  virtual clock (``Tracer.virtual_clock``, wired to the serving engine's
+  deterministic ``VirtualClock``; ``None`` outside one).  Virtual
+  timestamps make traces replayable and byte-identical in tests.
+  ``event`` records zero-length annotations on the ``Tracer`` only.
 
-Zero-cost when disabled: the module-level ``span()`` / ``event()``
-helpers read one context var and return the shared ``NOOP_SPAN`` when no
-tracer is active — no allocation, no clock reads, and (because all
-instrumentation is host-side) no change to any jit trace or cache
-(pinned by the no-recompile test in ``tests/test_obs.py``).
+Disabled path: with no ``Tracer`` active and no profile being collected,
+``span`` reads one context var, asks the profiler whether it is enabled,
+and returns the shared ``NOOP_SPAN``.  All of it is host-side, so no jit
+trace or cache changes either way (pinned in ``tests/test_obs.py``).
 
 Determinism: span ids are assigned in creation order, so the same
 seeded serving trace produces the identical span tree; exporting with
@@ -37,6 +44,8 @@ import contextlib
 import time
 from contextvars import ContextVar
 from dataclasses import dataclass, field
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Span", "Tracer", "NOOP_SPAN", "active", "span", "event", "use"]
 
@@ -90,35 +99,48 @@ class Span:
 
 
 class _SpanHandle:
-    """Context manager returned by ``Tracer.span``: enters by pushing the
-    span onto the current-span context var, exits by stamping end times
-    and popping.  ``set_attr`` works before and after exit (stage
-    instrumentation attaches modeled times post-fold)."""
+    """Context manager returned by ``span``: enters the profiler
+    annotation ``me`` (when a profile is being collected) and the
+    ``Tracer`` span (when a tracer is active; ``span`` is then that
+    ``Span``, else ``None``).  Entering pushes the span onto the
+    current-span context var; exiting stamps end times and pops.
+    ``set_attr`` works before and after exit and writes to the ``Tracer``
+    only (the executor attaches the folded ledger after the fold)."""
 
-    __slots__ = ("_tracer", "span", "_token")
+    __slots__ = ("_tracer", "span", "_token", "_me")
 
-    def __init__(self, tracer: "Tracer", sp: Span):
+    def __init__(self, tracer: "Tracer | None", sp: Span | None,
+                 me: TraceAnnotation | None = None):
         self._tracer = tracer
         self.span = sp
         self._token = None
+        self._me = me
 
     def set_attr(self, key: str, value) -> None:
-        self.span.attrs[key] = value
+        if self.span is not None:
+            self.span.attrs[key] = value
 
     def set_attrs(self, **kv) -> None:
-        self.span.attrs.update(kv)
+        if self.span is not None:
+            self.span.attrs.update(kv)
 
     def __enter__(self) -> "_SpanHandle":
-        self._token = self._tracer._current.set(self.span.sid)
+        if self._me is not None:
+            self._me.__enter__()
+        if self.span is not None:
+            self._token = self._tracer._current.set(self.span.sid)
         return self
 
     def __exit__(self, *exc) -> bool:
         sp = self.span
-        sp.wall_end_s = time.perf_counter()
-        clock = self._tracer.virtual_clock
-        if clock is not None:
-            sp.virtual_end_us = float(clock())
-        self._tracer._current.reset(self._token)
+        if sp is not None:
+            sp.wall_end_s = time.perf_counter()
+            clock = self._tracer.virtual_clock
+            if clock is not None:
+                sp.virtual_end_us = float(clock())
+            self._tracer._current.reset(self._token)
+        if self._me is not None:
+            self._me.__exit__(*exc)
         return False
 
 
@@ -167,15 +189,15 @@ class Tracer:
         self.spans.append(sp)
         return sp
 
-    def span(self, name: str, *, track: str = "main", **attrs) -> _SpanHandle:
-        """Open a timed span nested under the current one (context
-        manager).  Wall start stamps immediately; virtual start stamps
-        when a virtual clock is attached."""
+    def _open(self, name: str, track: str, attrs: dict) -> Span:
+        """A timed span nested under the current one, for ``span`` to
+        enter.  Wall start stamps now; virtual start stamps when a virtual
+        clock is attached."""
         sp = self._fresh(name, track, self._current.get(), attrs)
         sp.wall_start_s = time.perf_counter()
         if self.virtual_clock is not None:
             sp.virtual_start_us = float(self.virtual_clock())
-        return _SpanHandle(self, sp)
+        return sp
 
     def event(self, name: str, *, track: str = "main",
               parent: int | None = None, virtual_us: float | None = None,
@@ -221,25 +243,37 @@ class Tracer:
 
 # ---------------------------------------------------------- module helpers
 # Instrumentation sites call these, not Tracer methods: one context-var
-# read when disabled, nothing else.
+# read and one profiler check when disabled, nothing else.
 
 
 def active() -> Tracer | None:
-    """The tracer activated by ``use`` (None = tracing disabled)."""
+    """The tracer activated by ``use`` (None = no tracer)."""
     return _ACTIVE.get()
 
 
+def _annotation(name: str, attrs: dict) -> TraceAnnotation:
+    """The profiler's host span for ``span(name, **attrs)``: the scalar
+    attributes become its arguments; lists, dicts and ``None`` stay off."""
+    return TraceAnnotation(f"fatrq.{name}", **{
+        k: v for k, v in attrs.items() if isinstance(v, (int, float, str))})
+
+
 def span(name: str, *, track: str = "main", **attrs):
-    """Open a span on the active tracer; the shared no-op handle when
-    tracing is disabled (the zero-cost fast path)."""
+    """Open a span on the active tracer and, while a profile is being
+    collected, on the profiler; the shared no-op handle when neither is
+    on (the disabled fast path)."""
     tr = _ACTIVE.get()
-    if tr is None:
-        return NOOP_SPAN
-    return tr.span(name, track=track, **attrs)
+    me = _annotation(name, attrs) if TraceAnnotation.is_enabled() else None
+    if tr is not None:
+        return _SpanHandle(tr, tr._open(name, track, attrs), me)
+    if me is not None:
+        return _SpanHandle(None, None, me)
+    return NOOP_SPAN
 
 
 def event(name: str, *, track: str = "main", **attrs) -> Span | None:
-    """Record an event on the active tracer; no-op when disabled."""
+    """Record an event on the active tracer; no-op without one.  Events
+    never reach the profiler."""
     tr = _ACTIVE.get()
     if tr is None:
         return None

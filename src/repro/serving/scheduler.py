@@ -270,10 +270,10 @@ class ServingEngine:
         self._refine_free_us = 0.0
         self._busy_free_us = 0.0
 
-        # observability: a per-engine metrics registry (activated around
-        # ``run`` so executor-level series like fatrq_model_drift_ratio
-        # aggregate here, not in the process default) + an optional
-        # tracer whose virtual clock is wired to the engine's.
+        # observability: a per-engine metrics registry (activated while the
+        # engine runs, so series recorded beneath it aggregate here, not in
+        # the process default) + an optional tracer whose virtual clock is
+        # wired to the engine's.
         self.registry = MetricsRegistry()
         self.tracer = tracer
         if tracer is not None and tracer.virtual_clock is None:
@@ -412,38 +412,43 @@ class ServingEngine:
         self._m_occupancy.observe(len(batch))
         for a in batch:
             self._m_queue_wait.observe(now - a.admit_us)
-        trace.event("serve.dispatch", track="sched", bid=bid, k=rk,
-                    degraded=degraded, n=len(batch),
-                    rids=[a.rid for a in batch])
-        cp = self.db.compiled(self._class_plan(rk, degraded), mesh=self.mesh)
-        q = jnp.stack([jnp.asarray(a.req.query, jnp.float32) for a in batch])
-        n = q.shape[0]
-        if self.overlap and cp.supports_split:
-            bucket = bucket_for(n, self.max_batch)
-            qpad, qvalid = pad_chunk(q, bucket)
-            self.stats.padded_slots += bucket - n
-            cand = cp.run_front(qpad, qvalid=qvalid)
-            # retire the PREVIOUS batch's refine only after this front is
-            # enqueued — the double buffer.
-            self._retire_inflight(responses)
+        n = len(batch)
+        with trace.span("serve.dispatch", track="sched", bid=bid, k=rk,
+                        degraded=degraded, n=n, rids=[a.rid for a in batch]):
+            cp = self.db.compiled(self._class_plan(rk, degraded),
+                                  mesh=self.mesh)
+            q = jnp.stack([jnp.asarray(a.req.query, jnp.float32)
+                           for a in batch])
+            split = self.overlap and cp.supports_split
+            if split:
+                bucket = bucket_for(n, self.max_batch)
+                qpad, qvalid = pad_chunk(q, bucket)
+                self.stats.padded_slots += bucket - n
+                cand = cp.run_front(qpad, qvalid=qvalid)
+        # retire the PREVIOUS batch's refine only after this front is
+        # enqueued — the double buffer.
+        self._retire_inflight(responses)
+        if split:
             self._inflight = _Inflight(bid=bid, batch=batch, cp=cp,
                                        qpad=qpad, cand=cand, n=n,
                                        dispatch_us=now, degraded=degraded)
         else:
-            self._retire_inflight(responses)
-            res = cp.execute(q, pad=True)   # executor buckets internally
-            self.stats.padded_slots += bucket_for(n, self.max_batch) - n
-            self._complete(bid, batch, cp, res, n, now, degraded, responses,
-                           split=False)
+            with trace.span("serve.retire", track="sched", bid=bid):
+                res = cp.execute(q, pad=True)   # executor buckets internally
+                self.stats.padded_slots += bucket_for(n, self.max_batch) - n
+                self._complete(bid, batch, cp, res, n, now, degraded,
+                               responses, split=False)
 
     def _retire_inflight(self, responses: list) -> None:
         fl = self._inflight
         if fl is None:
             return
         self._inflight = None
-        res = fl.cp.run_finish(fl.qpad, fl.cand)
-        self._complete(fl.bid, fl.batch, fl.cp, res, fl.n, fl.dispatch_us,
-                       fl.degraded, responses, split=True)
+        with trace.span("serve.retire", track="sched", bid=fl.bid):
+            res = fl.cp.run_finish(fl.qpad, fl.cand)
+            self._complete(fl.bid, fl.batch, fl.cp, res, fl.n,
+                           fl.dispatch_us, fl.degraded, responses,
+                           split=True)
 
     # -- completion --------------------------------------------------------
 
@@ -491,8 +496,9 @@ class ServingEngine:
                             virtual_start_us=start, virtual_end_us=done,
                             parent=sp.sid, bid=bid)
         self.total_cost.merge(cost)
-        ids = np.asarray(res.ids[:n])
-        dists = np.asarray(res.distances[:n])
+        with trace.span("wait", track="sched", bid=bid):
+            ids = np.asarray(res.ids[:n])
+            dists = np.asarray(res.distances[:n])
         for i, adm in enumerate(batch):
             if self.cache is not None and adm.qkey is not None:
                 self.cache.insert(adm.qkey, cp.plan, cp.generation,
@@ -517,11 +523,16 @@ class ServingEngine:
         engine's tracer, when one was attached), so datapath series and
         spans recorded deep in the executor land with the engine's own.
         """
+        with self._observed():
+            return self._run(requests)
+
+    @contextlib.contextmanager
+    def _observed(self):
         with contextlib.ExitStack() as stack:
             stack.enter_context(obs_metrics.use(self.registry))
             if self.tracer is not None:
                 stack.enter_context(trace.use(self.tracer))
-            return self._run(requests)
+            yield
 
     def _run(self, requests: list) -> list:
         pending = sorted(
@@ -548,8 +559,11 @@ class ServingEngine:
                 i += 1
             # EDF admission order at this instant.
             arrivals.sort(key=lambda r: (r.deadline_us, r.arrival_us, r.rid))
-            for req in arrivals:
-                self._admit(req, responses)
+            if arrivals:
+                with trace.span("serve.admit", track="sched",
+                                n=len(arrivals)):
+                    for req in arrivals:
+                        self._admit(req, responses)
             self._dispatch_ready(responses)
         self._dispatch_ready(responses, drain=True)
         self._retire_inflight(responses)
@@ -565,12 +579,15 @@ class ServingEngine:
               tenant: str = "default") -> list:
         """Convenience: submit one request per row at the current clock
         instant and run to drain.  Responses come back in input order."""
-        queries = jnp.asarray(queries, jnp.float32)
-        now = self.clock.now_us
-        reqs = [Request(query=queries[i], tenant=tenant, k=k,
-                        arrival_us=now, rid=self._fresh_rid())
-                for i in range(queries.shape[0])]
-        return self.run(reqs)
+        with self._observed(), \
+                trace.span("serve", track="sched", n=len(queries)):
+            with trace.span("serve.requests", track="sched"):
+                queries = jnp.asarray(queries, jnp.float32)
+                now = self.clock.now_us
+                reqs = [Request(query=queries[i], tenant=tenant, k=k,
+                                arrival_us=now, rid=self._fresh_rid())
+                        for i in range(queries.shape[0])]
+            return self._run(reqs)
 
 
 # ----------------------------------------------------------- RAG serving

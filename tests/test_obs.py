@@ -12,6 +12,9 @@ The load-bearing pins:
   helpers return the shared no-op handle, no spans are recorded, and a
   traced run leaves every stage jit cache untouched (instrumentation is
   host-side only — it can never grow a jit cache);
+* **no sync, two sinks** — traced paths never wait on the device; under
+  a ``jax.profiler`` trace the spans land in the profile, nested as the
+  engine runs them, and each stage program names its ``fatrq.*`` scopes;
 * **deterministic exports** — the same seeded serving trace exports a
   byte-identical wall-stripped JSONL and Chrome-trace JSON across runs,
   and the Chrome trace shows batch N+1's front overlapping batch N's
@@ -19,6 +22,7 @@ The load-bearing pins:
 """
 
 import json
+import warnings
 
 import jax
 import numpy as np
@@ -92,13 +96,13 @@ def test_set_attr_after_exit_and_wall_prefix_stripping():
     with trace.use(tr):
         with trace.span("s", keep=1) as h:
             pass
-        h.set_attr("wall_model_drift", 3.5)
-        h.set_attrs(model_s=2.0)
+        h.set_attr("wall_lag_s", 3.5)
+        h.set_attrs(ledger_bytes=2.0)
     rec = tr.spans[0].to_record(include_wall=False)
-    assert rec["attrs"] == {"keep": 1, "model_s": 2.0}
+    assert rec["attrs"] == {"keep": 1, "ledger_bytes": 2.0}
     assert "wall_start_s" not in rec
     full = tr.spans[0].to_record(include_wall=True)
-    assert full["attrs"]["wall_model_drift"] == 3.5
+    assert full["attrs"]["wall_lag_s"] == 3.5
 
 
 def test_virtual_clock_stamping():
@@ -281,21 +285,10 @@ def test_serving_metrics_unified_flat_dict(ds, index):
     assert flat['serving_cache{field="misses"}'] == eng.cache.stats.misses
     assert flat["serving_queue_wait_us_count"] > 0
     assert flat["serving_batch_occupancy_count"] == eng.stats.batches
-    # datapath drift series landed in the ENGINE registry (context-routed)
-    assert flat['fatrq_model_drift_ratio_count{stage="refine"}'] > 0
-    assert flat['fatrq_model_drift_ratio_count{stage="front"}'] > 0
     text = export.prometheus_text(eng.registry)
     for series in ("serving_queue_wait_us", "serving_batch_occupancy",
-                   "serving_cache", "fatrq_model_drift_ratio",
-                   "serving_stats"):
+                   "serving_cache", "serving_stats"):
         assert series in text
-
-
-def test_model_drift_only_when_traced(ds, index):
-    eng = _engine(index)                   # no tracer
-    eng.run(_requests(ds))
-    assert not any(k.startswith("fatrq_model_drift")
-                   for k in eng.metrics())
 
 
 def test_streaming_mutation_events_and_metrics(ds, index):
@@ -339,3 +332,135 @@ def test_compile_cache_span(ds, index):
     probes = tr.by_name("plan.compile")
     assert [p.attrs["cache_hit"] for p in probes] == [False, True]
     assert len(tr.by_name("plan.compile.build")) == 1
+
+
+# ------------------------------------------- profiler sink and device scopes
+
+
+_SCOPES = {
+    "ivf_candidates": ("fatrq.front.probe", "fatrq.front.adc"),
+    "pallas_refine": ("fatrq.refine.gather", "fatrq.refine.kernel"),
+    "rerank_survivors": ("fatrq.rerank",),
+}
+
+
+def _stage_program(name, ds, index):
+    """The stage jit ``name`` lowered for one 8-query micro-batch."""
+    from repro.anns import stages
+    q = ds.queries[:8]
+    cfg = index.config
+    args = (index.ivf, index.codebook, index.pq_codes, q, None)
+    if name == "ivf_candidates":
+        return stages._ivf_candidates.lower(*args, nprobe=cfg.nprobe)
+    ids, valid, d0, _ = stages._ivf_candidates(*args, nprobe=cfg.nprobe)
+    if name == "pallas_refine":
+        return stages._pallas_refine.lower(
+            q, d0, ids, valid, None, index.trq, k=5, bound=cfg.bound,
+            z=cfg.z, block_c=128)
+    return stages._rerank_survivors.lower(index.x, q, ids, d0, valid, k=5,
+                                          budget=20)
+
+
+@pytest.mark.parametrize("program", sorted(_SCOPES))
+def test_stage_programs_name_their_scopes(ds, index, program):
+    """Each layer's device work carries its named scope in the lowered
+    module's locations and in the compiled HLO's op names — the op path a
+    profile gives every device op."""
+    lowered = _stage_program(program, ds, index)
+    text = lowered.as_text(debug_info=True)
+    compiled = lowered.compile().as_text()
+    for scope in _SCOPES[program]:
+        assert f"/{scope}/" in text, scope
+        assert f"/{scope}/" in compiled, scope
+
+
+def _host_spans(log_dir):
+    """fatrq.* host spans of a profile: [(name, start, end, stats, line)]."""
+    import glob
+    import os
+    path, = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    out = []
+    with warnings.catch_warnings():        # the stats' type lacks a module
+        warnings.simplefilter("ignore", DeprecationWarning)
+        for plane in jax.profiler.ProfileData.from_file(path).planes:
+            if plane.name != "/host:CPU":
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("fatrq."):
+                        out.append((e.name, e.start_ns,
+                                    e.start_ns + e.duration_ns,
+                                    dict(e.stats), line.name))
+    return out
+
+
+def test_serve_spans_reach_the_profiler_nested(ds, index, tmp_path):
+    """Without a Tracer, a profile of ``serve`` holds the program's spans
+    at every layer boundary, nested as the engine runs them: each batch's
+    dispatch (with its front) and retire (with refine, rerank, fold) under
+    the call, and every wait for the device inside the call.  The engine's
+    spans carry the batch id ``bid``."""
+    eng = ServingEngine(index, max_batch=4)
+    eng.serve(ds.queries)                  # compile outside the profile
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.serve(ds.queries)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(str(tmp_path))
+    by = {}
+    for s in spans:
+        by.setdefault(s[0], []).append(s)
+
+    def inside(child, parents):
+        return any(p[4] == child[4] and p[1] <= child[1] and child[2] <= p[2]
+                   for p in parents)
+
+    serve = by["fatrq.serve"]
+    assert len(serve) == 1
+    for name in ("fatrq.serve.requests", "fatrq.serve.admit",
+                 "fatrq.serve.dispatch", "fatrq.serve.retire", "fatrq.wait"):
+        assert by[name] and all(inside(s, serve) for s in by[name]), name
+    assert all(inside(s, by["fatrq.serve.dispatch"]) for s in by["fatrq.front"])
+    for name in ("fatrq.refine", "fatrq.rerank", "fatrq.fold"):
+        assert by[name] and all(inside(s, by["fatrq.serve.retire"])
+                                for s in by[name]), name
+    assert any(inside(s, by["fatrq.fold"]) for s in by["fatrq.wait"])
+    bids = {s[3]["bid"] for s in by["fatrq.serve.dispatch"]}
+    assert bids == {s[3]["bid"] for s in by["fatrq.serve.retire"]}
+    assert len(bids) == len(by["fatrq.serve.dispatch"]) == 2
+    # the executor's spans take their batch from the engine span holding
+    # them: one front in each dispatch, one finish in each retire
+    for outer, inner in (("fatrq.serve.dispatch", "fatrq.front"),
+                         ("fatrq.serve.retire", "fatrq.finish")):
+        assert sorted(sum(inside(s, [p]) for s in by[inner])
+                      for p in by[outer]) == [1, 1], inner
+
+
+def test_traced_paths_never_block_on_the_device(ds, index, monkeypatch):
+    """With a Tracer active, queries and serving run with
+    ``jax.block_until_ready`` unusable and answer bit-identically to the
+    untraced run: a span measures host time and never syncs."""
+    db = Database.wrap(index)
+    plan = QueryPlan(backend="pallas", micro_batch=4, k=5)
+    want = db.query(ds.queries, plan=plan)
+    want_served = ServingEngine(index, max_batch=4).serve(ds.queries)
+
+    def refuse(*_, **__):
+        raise AssertionError("block_until_ready called while tracing")
+
+    monkeypatch.setattr(jax, "block_until_ready", refuse)
+    tr = trace.Tracer()
+    with trace.use(tr):
+        got = db.query(ds.queries, plan=plan)
+    served = ServingEngine(index, max_batch=4, tracer=trace.Tracer()) \
+        .serve(ds.queries)
+    assert {"front", "refine", "rerank", "fold", "wait"} <= \
+        {s.name for s in tr.spans}
+    assert np.array_equal(np.asarray(got.ids), np.asarray(want.ids))
+    assert np.array_equal(np.asarray(got.distances),
+                          np.asarray(want.distances))
+    for a, b in zip(served, want_served):
+        assert np.array_equal(a.ids, b.ids)
+        assert np.array_equal(a.distances, b.distances)
