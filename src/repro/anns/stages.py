@@ -48,6 +48,7 @@ from repro.core.trq import TRQCodes
 from repro.index import graph as graph_mod
 from repro.index import ivf as ivf_mod
 from repro.kernels import ops as kernel_ops
+from repro.kernels import pq_adc
 from repro.memory import QueryCost, RecordLayout, Tier
 from repro.quant import pq as pq_mod
 
@@ -167,11 +168,16 @@ def adc_score(codebook: pq_mod.PQCodebook, pq_codes: jax.Array,
               valid: jax.Array) -> jax.Array:
     """Batched PQ-ADC scoring of per-query candidates ``ids`` (Q, C):
     gathers their PQ codes (Q, C, M) and scores them, +inf outside
-    ``valid``.  Shared by every front, sharded or not."""
+    ``valid``.  Shared by every front, sharded or not.  On a TPU the
+    scores come from the one-hot MXU kernel (``kernels/pq_adc.py``),
+    elsewhere from the table gather of ``quant.pq``."""
     with jax.named_scope("fatrq.front.adc"):
         codes = pq_codes[ids]
         tables = jax.vmap(lambda q: pq_mod.adc_table(codebook, q))(queries)
-        d0 = jax.vmap(pq_mod.adc_distances)(tables, codes)
+        if pq_adc.use_kernel():
+            d0 = pq_adc.pq_adc_batch(codes, tables)
+        else:
+            d0 = jax.vmap(pq_mod.adc_distances)(tables, codes)
         return jnp.where(valid, d0, jnp.inf)
 
 

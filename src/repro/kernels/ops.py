@@ -15,15 +15,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.pq_adc import pq_adc
-from repro.kernels.ternary_refine import (ternary_refine,
+from repro.kernels.pq_adc import pq_adc_batch
+from repro.kernels.ternary_refine import (VMEM_BUDGET_BYTES, ternary_refine,
                                           ternary_refine_batch,
                                           ternary_refine_fused,
                                           ternary_refine_fused_bounds)
-
-#: Per-core VMEM the kernels budget against: the TPU compiler's default
-#: scoped-VMEM limit on v4/v5e (16 MiB); the kernels set no other limit.
-VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 
 
 class VMEMBudgetError(ValueError):
@@ -243,9 +239,7 @@ def fused_refine_bounds_batch(packed_levels: jax.Array, q: jax.Array,
     return est[:, :c0], lo[:, :, :c0], hi[:, :, :c0]
 
 
-@functools.partial(jax.jit, static_argnames=("block_c",))
-def adc_scores(codes: jax.Array, lut: jax.Array, *, block_c: int = 128
-               ) -> jax.Array:
-    """PQ-ADC distances for a candidate batch → (C,)."""
-    codes_p, c0 = _pad_rows(codes, block_c)
-    return pq_adc(codes_p, lut.astype(jnp.float32), block_c=block_c)[:c0]
+def adc_scores(codes: jax.Array, lut: jax.Array) -> jax.Array:
+    """PQ-ADC distances of one query's candidates: codes (C, M) uint8,
+    lut (M, K) → (C,), by the batched kernel at Q = 1."""
+    return pq_adc_batch(codes[None], lut[None])[0]

@@ -52,6 +52,11 @@ from jax.experimental.pallas import tpu as pltpu
 _POW3 = (1, 3, 9, 27, 81)
 
 
+#: Per-core VMEM the kernels budget against: the TPU compiler's default
+#: scoped-VMEM limit on v4/v5e (16 MiB).
+VMEM_BUDGET_BYTES = 16 * 1024 * 1024
+
+
 def resolve_interpret(interpret: bool | None) -> bool:
     """None → decided when the kernel is traced: compiled when JAX's
     default backend is a TPU, the interpreter everywhere else.  Nothing

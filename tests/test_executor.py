@@ -281,3 +281,88 @@ class TestGraphPrimitives:
         g = np.asarray(graph_mod.build(x, degree=8).neighbors)
         with pytest.raises(ValueError, match="zero live rows"):
             graph_mod.compact_graph(g, x, np.array([], np.int64))
+
+
+class TestADCKernelPath:
+    """Every front scores its candidates through ``stages.adc_score``; on a
+    TPU that is the MXU kernel of ``kernels/pq_adc.py``.  Here the platform
+    choice is steered onto the kernel (run in interpret mode) and each
+    call's d̂₀ is checked against the table gather on the same codes."""
+
+    @pytest.fixture(scope="class")
+    def small(self):
+        from repro.anns import StreamingConfig, StreamingIndex
+        d = make_dataset(jax.random.PRNGKey(0), n=2500, d=32, n_queries=8,
+                         k_gt=20, clusters=8)
+        cfg = PipelineConfig(dim=32, pq_m=4, pq_k=32, nlist=16, nprobe=4,
+                             final_k=5, refine_budget=20)
+        st = StreamingIndex(build(jax.random.PRNGKey(2), d.x[:2000], cfg),
+                            StreamingConfig(auto_compact=False))
+        st.insert(d.x[2000:])                 # delta lists
+        st.delete(np.arange(100, 200))        # tombstones
+        return d, build(jax.random.PRNGKey(1), d.x, cfg), st
+
+    @staticmethod
+    def _on_kernel(mp):
+        """Route ``adc_score`` onto the kernel; return the list each
+        kernel call appends (kernel d̂₀, gather d̂₀) to."""
+        from repro.kernels import pq_adc
+        from repro.quant import pq
+        seen = []
+        kernel = pq_adc.pq_adc_batch
+
+        def spy(codes, tables):
+            d0 = kernel(codes, tables)
+            ref = jax.vmap(pq.adc_distances)(tables, codes)
+            jax.debug.callback(
+                lambda a, b: seen.append((np.asarray(a), np.asarray(b))),
+                d0, ref)
+            return d0
+
+        mp.setattr(pq_adc, "use_kernel", lambda: True)
+        mp.setattr(pq_adc, "pq_adc_batch", spy)
+        jax.clear_caches()
+        return seen
+
+    @pytest.mark.parametrize("front", ["ivf", "graph"])
+    def test_candidates_match_gather(self, small, front, monkeypatch):
+        ds_, idx, _ = small
+        stage = make_executor(idx, front=front).front
+        qvalid = jnp.arange(ds_.queries.shape[0]) < 6     # two padded rows
+        want = stage.candidates(ds_.queries, qvalid)
+        with monkeypatch.context() as mp:
+            seen = self._on_kernel(mp)
+            got = stage.candidates(ds_.queries, qvalid)
+            jax.effects_barrier()
+        jax.clear_caches()
+        assert len(seen) == 1
+        np.testing.assert_allclose(seen[0][0], seen[0][1], rtol=1e-5,
+                                   atol=1e-6)
+        assert jnp.array_equal(got.ids, want.ids)
+        assert jnp.array_equal(got.valid, want.valid)
+        assert bool(jnp.all(jnp.where(got.valid, True,
+                                      got.d0 == jnp.inf)))
+        assert not bool(jnp.all(got.valid))      # some slots are invalid
+        np.testing.assert_allclose(np.asarray(got.d0), np.asarray(want.d0),
+                                   rtol=1e-5, atol=1e-6)
+
+    @pytest.mark.parametrize("layout", ["static", "sharded", "streaming"])
+    @pytest.mark.parametrize("front", ["ivf", "graph"])
+    def test_search_ids_unchanged(self, small, layout, front, monkeypatch):
+        from repro.anns import Database, QueryPlan
+        ds_, idx, st = small
+        db = Database.wrap(st if layout == "streaming" else idx)
+        plan = QueryPlan(front=front, backend="pallas",
+                         shards=1 if layout == "sharded" else None)
+        want = db.query(ds_.queries, plan=plan)
+        with monkeypatch.context() as mp:
+            seen = self._on_kernel(mp)
+            got = db.query(ds_.queries, plan=plan)
+            jax.effects_barrier()
+        jax.clear_caches()
+        assert seen, "the front did not score through the kernel"
+        for d0, ref in seen:
+            np.testing.assert_allclose(d0, ref, rtol=1e-5, atol=1e-6)
+        assert jnp.array_equal(got.ids, want.ids)
+        np.testing.assert_array_equal(np.asarray(got.distances),
+                                      np.asarray(want.distances))

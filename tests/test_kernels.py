@@ -340,17 +340,50 @@ class TestCertificationSoundness:
 
 
 class TestADCKernel:
-    @pytest.mark.parametrize("c,m,k", [(64, 8, 32), (128, 16, 256),
-                                       (500, 32, 64), (13, 4, 16),
-                                       (256, 96, 256)])
-    def test_matches_ref(self, c, m, k):
+    # (Q, C, M, K): the batched kernel against the gather of quant.pq, per
+    # query.  M ∈ {4, 96, 192} at K = 256, K ∈ {16, 32, 64}, C off the
+    # candidate block (13, 130, 300, 500), Q ∈ {1, 3}.
+    @pytest.mark.parametrize("nq,c,m,k", [
+        (1, 64, 8, 32), (1, 128, 16, 256), (1, 500, 32, 64), (1, 13, 4, 16),
+        (1, 256, 96, 256), (3, 300, 4, 256), (3, 300, 96, 256),
+        (1, 200, 192, 256), (3, 130, 8, 16), (3, 129, 16, 64)])
+    def test_matches_ref(self, nq, c, m, k):
+        from repro.kernels.pq_adc import pq_adc_batch
+        from repro.quant import pq
         key = jax.random.PRNGKey(c + m + k)
-        codes = jax.random.randint(key, (c, m), 0, k).astype(jnp.uint8)
-        lut = jax.random.uniform(jax.random.fold_in(key, 1), (m, k))
-        out = adc_scores(codes, lut)
-        expect = ref.pq_adc_ref(codes, lut)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
-                                   rtol=1e-5, atol=1e-5)
+        codes = jax.random.randint(key, (nq, c, m), 0, k).astype(jnp.uint8)
+        luts = jax.random.uniform(jax.random.fold_in(key, 1), (nq, m, k))
+        out = pq_adc_batch(codes, luts)
+        assert out.shape == (nq, c) and out.dtype == jnp.float32
+        for qi in range(nq):
+            np.testing.assert_allclose(
+                np.asarray(out[qi]),
+                np.asarray(pq.adc_distances(luts[qi], codes[qi])),
+                rtol=1e-5, atol=1e-6)
+        if nq == 1:        # the single-query entry is the same kernel
+            np.testing.assert_array_equal(
+                np.asarray(adc_scores(codes[0], luts[0])), np.asarray(out[0]))
+
+    def test_lut_parts_restore_f32_exactly(self):
+        """(hi + mid) + lo of the bf16 split is the f32 entry, bit for bit,
+        each part is cut without rounding, and each chunk block is
+        block-diagonal over its subspaces."""
+        from repro.kernels import pq_adc as P
+        luts = jax.random.normal(jax.random.PRNGKey(3), (2, 16, 256)) * 1e3
+        plan = P.adc_plan(100, 16, 256)
+        parts = P._lut_parts(luts, plan).astype(jnp.float32)
+        mc, ka, kb = P._MC, plan.ka, P._KB
+        blocks = parts.reshape(2, 16 // mc, 3, mc, ka, mc, kb)
+        diag = jnp.einsum("qjxiaib->qjxiab", blocks)
+        whole = (diag[:, :, 0] + diag[:, :, 1]) + diag[:, :, 2]
+        np.testing.assert_array_equal(
+            np.asarray(whole.reshape(2, 16, ka * kb)), np.asarray(luts))
+        # truncation, not rounding: hi and mid keep the sign of the entry
+        # and no larger magnitude, so no part was rounded up
+        for x in (diag[:, :, 0], diag[:, :, 1]):
+            assert bool(jnp.all(jnp.abs(x) <= jnp.abs(whole)))
+        eye = jnp.eye(mc, dtype=bool)[:, None, :, None]     # (i, ·, i', ·)
+        assert not bool(jnp.any(jnp.where(eye, 0.0, blocks) != 0.0))
 
     def test_matches_pq_module(self):
         from repro.quant import pq

@@ -2,7 +2,8 @@
 
 The fused refine kernels and the jitted Pallas refine step are compiled at
 the widths ``chip_smoke.py`` runs (d=768 → G=154 code bytes, L=2 levels, a
-32-query micro-batch over 47,104 padded candidates, 512-candidate blocks).
+32-query micro-batch over 47,104 padded candidates, 512-candidate blocks);
+the served IVF front with its MXU ADC kernel at both benchmark shapes.
 The TPU compiler refuses block shapes, scratch layouts and VMEM use that
 interpret mode accepts, so these tests guard the chip path without a chip.
 Nothing runs: they check that a Mosaic kernel is in the program and that
@@ -101,3 +102,38 @@ def test_pallas_refine_step_compiles_for_v5e(one_chip, monkeypatch):
                           s((Q, C), jnp.bool_), codes).compile()
     jax.clear_caches()
     _check(compiled)
+
+
+@pytest.mark.parametrize("n,d,m,cap", [(1_000_000, 768, 96, 2_930),
+                                       (500_000, 1_536, 192, 1_713)])
+def test_ivf_front_compiles_for_v5e(one_chip, monkeypatch, n, d, m, cap):
+    """The served front (probe, code gather, ADC) at the benchmark's
+    shapes: 16 probed lists of ``cap`` slots, 46,880 and 27,408 candidates
+    a query.  It scores on the MXU kernel, and no (Q, C, M) f32 table
+    lookup (576 and 674 MB) is left among its temporaries."""
+    from repro.anns import stages
+    from repro.index import ivf as ivf_mod
+    from repro.kernels import pq_adc
+    from repro.quant import pq as pq_mod
+
+    # the CPU is the default backend here: steer the front onto the kernel,
+    # and the kernel to its compiled form
+    monkeypatch.setattr(pq_adc, "use_kernel", lambda: True)
+    monkeypatch.setattr(pq_adc, "resolve_interpret",
+                        lambda interpret: False if interpret is None
+                        else bool(interpret))
+    jax.clear_caches()
+    s = lambda shape, dtype=jnp.float32: _spec(one_chip, shape, dtype)  # noqa: E731
+    nlist, nprobe, k = 1024, 16, 256
+    ivf = ivf_mod.IVFIndex(centroids=s((nlist, d)),
+                           lists=s((nlist, cap), jnp.int32),
+                           list_len=s((nlist,), jnp.int32))
+    codebook = pq_mod.PQCodebook(codebooks=s((m, k, d // m)))
+    front = jax.jit(lambda ivf, cb, codes, q, qv: stages._ivf_candidates(
+        ivf, cb, codes, q, qv, nprobe=nprobe))
+    compiled = front.lower(ivf, codebook, s((n, m), jnp.uint8), s((Q, d)),
+                           s((Q,), jnp.bool_)).compile()
+    jax.clear_caches()
+    _check(compiled)
+    lookup = Q * nprobe * cap * m * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < lookup
