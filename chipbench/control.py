@@ -1,10 +1,10 @@
 """The control: the reference one precision lower, in the system's place.
 
-``correct`` must call it not correct.  It is the exact brute force of
-``reference.py`` computed in bfloat16 (the precision below the float32
-that the configurations state), answering the same requests through the
-same window, loop and comparison as a run of the cell.  Readings on the
-chip, at a cell's own size:
+``correct`` must call it not correct.  It is the exact brute force of the
+configuration's reference (``control_answers``) computed in bfloat16 (the
+precision below the float32 that the configurations state), answering the
+same requests through the same window, loop and comparison as a run of
+the cell.  Readings on the chip, at a cell's own size:
 
     python3 chipbench/control.py --workload <cell> --seeds 1,2,3
 
@@ -27,17 +27,16 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 class ControlEngine:
-    """Answers ``serve`` calls with the bfloat16 brute force."""
+    """Answers ``serve`` calls with a reference's bfloat16 brute force."""
 
-    def __init__(self, x, k: int):
-        self.x, self.k = x, k
+    def __init__(self, x, k: int, reference):
+        self.x, self.k, self.reference = x, k, reference
         self.stats = types.SimpleNamespace(requests=0)
 
     def serve(self, queries):
         import jax.numpy as jnp
-        from chipbench import reference
-        ids, dists = reference.control_answers(self.x, jnp.asarray(queries),
-                                               self.k)
+        ids, dists = self.reference.control_answers(
+            self.x, jnp.asarray(queries), self.k)
         base = self.stats.requests
         self.stats.requests += len(queries)
         return [types.SimpleNamespace(rid=base + i, ids=ids[i],
@@ -48,10 +47,11 @@ class ControlEngine:
 
 def system(parts: dict, seed: int):
     """``run.run_cell``'s ``system``: the corpus and the control engine."""
-    from chipbench import data
+    data = parts["generator"]
     spec = data.Spec(parts["config"])
     return (ControlEngine(data.corpus(spec),
-                          parts["config"]["pipeline"]["final_k"]), spec)
+                          parts["config"]["pipeline"]["final_k"],
+                          parts["reference"]), spec)
 
 
 def main(argv=None) -> int:

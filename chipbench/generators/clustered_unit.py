@@ -1,4 +1,5 @@
-"""Corpus and query generator of the benchmark, made on the device.
+"""Generator ``clustered_unit``: unit-norm corpus and in-distribution
+queries, made on the device.
 
 The statistics are those of the system's own synthetic generator: unit-norm
 embeddings around ``clusters`` random unit centres, with an anisotropic
@@ -25,7 +26,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-BLOCK_ROWS = 32_768
+from chipbench.common import block_rows as _block_rows
+from chipbench.common import key_data as _key_data
+from chipbench.common import seed_key
 
 
 class Spec:
@@ -47,17 +50,6 @@ class Spec:
                 self.decay_dims, self.query_noise)
 
 
-def seed_key(seed: int, stream: int = 0) -> jax.Array:
-    """A typed key from a seed of any size (the seed's two 32-bit halves)."""
-    key = jax.random.key(seed & 0xFFFFFFFF)
-    key = jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
-    return jax.random.fold_in(key, stream)
-
-
-def _key_data(seed: int, n: int) -> jax.Array:
-    return jax.random.key_data(jax.random.split(seed_key(seed), n))
-
-
 def _rows(keys, idx, spec: tuple) -> jax.Array:
     """Rows ``idx`` (B,) of the corpus → (B, dim) float32, unit norm.
     keys: centres, cluster ids, row noise."""
@@ -74,14 +66,6 @@ def _rows(keys, idx, spec: tuple) -> jax.Array:
 
     x = jax.vmap(one)(idx)
     return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
-
-
-def _block_rows(rows: int) -> int:
-    """Largest divisor of ``rows`` that is at most BLOCK_ROWS."""
-    for b in range(min(rows, BLOCK_ROWS), 0, -1):
-        if rows % b == 0:
-            return b
-    return 1
 
 
 @functools.partial(jax.jit, static_argnames=("spec",))

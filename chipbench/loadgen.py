@@ -110,10 +110,27 @@ class Queries:
 
 def query_indices(mix: Mix, seed: int, n: int) -> np.ndarray:
     """Pool index of the question each of the first n requests asks."""
-    b = mix.recall_set
-    blocks = [k * b + np.random.default_rng([seed, 4, k]).permutation(b)
-              for k in range(-(-n // b))]
-    return np.concatenate(blocks)[:n] if blocks else np.zeros(0, int)
+    return Order(mix, seed).take(0, n)
+
+
+class Order:
+    """``query_indices`` of a growing window, each block drawn once: the
+    window's own work between calls stays the same however long it runs."""
+
+    def __init__(self, mix: Mix, seed: int):
+        self._b, self._seed = mix.recall_set, seed
+        self._blocks: list[np.ndarray] = []
+
+    def take(self, j: int, n: int) -> np.ndarray:
+        """Indices of requests j .. j+n-1."""
+        b = self._b
+        while len(self._blocks) * b < j + n:
+            k = len(self._blocks)
+            rng = np.random.default_rng([self._seed, 4, k])
+            self._blocks.append(k * b + rng.permutation(b))
+        got = self._blocks[j // b:-(-(j + n) // b)]
+        return (np.concatenate(got)[j % b:j % b + n] if got
+                else np.zeros(0, int))
 
 
 def _record(win: Window, responses: list, qidx, t_send: float,
@@ -153,10 +170,10 @@ def run_window(engine, mix: Mix, seed: int, seconds: float, queries: Queries,
     """Drive the engine for ``seconds`` of wall time: until the call during
     which they elapse returns, and at least through the recall set."""
     win = Window()
+    order = Order(mix, seed)
     min_calls = math.ceil(mix.recall_set / mix.callers)
     while True:
-        j = win.attempted
-        qi = query_indices(mix, seed, j + mix.callers)[j:]
+        qi = order.take(win.attempted, mix.callers)
         with annotate("chipbench.queries", n=mix.callers):
             q = queries.get(qi)
         t_send = time.perf_counter()

@@ -6,17 +6,46 @@
 The cell is an entry of ``BENCHMARK.json``'s ``workloads``; its
 configuration is ``chipbench/configs/<config>.json``, its traffic mix
 ``chipbench/traffic/<traffic>.json`` (read by ``loadgen``), and each
-per-layer metric is read by ``chipbench/metrics/<metric>.py``.  Everything
-runs in this one process, which holds the cell's chips.  Without a TPU, or
-with fewer chips than the cell asks for, it exits non-zero and prints no
-result.
+per-layer metric is read by ``chipbench/metrics/<metric>.py``.  The
+configuration names its own semantics: its data generator
+(``"data": {"generator": "<g>", ...}``, ``chipbench/generators/<g>.py``)
+and its reference (``"reference": "<r>"``, ``chipbench/references/<r>.py``).
+Everything runs in this one process, which holds the cell's chips.
+Without a TPU, or with fewer chips than the cell asks for, it exits
+non-zero and prints no result.
+
+A generator gives:
+
+* ``Spec(cfg)``: what the configuration says about its data, with
+  ``.rows``, the corpus's row count;
+* ``corpus(spec)``: the whole corpus (rows, dim), the same for the same
+  configuration;
+* ``build_key(spec)``: the raw key the index is built with;
+* ``queries(spec, idx, *, warmup=False)``: queries number ``idx`` of the
+  configuration's pool, (len(idx), dim); the warm-up pool is disjoint from
+  the measured one.
+
+A reference gives:
+
+* ``compare(answers, x, q, *, rows, k, limits)``: judges served answers
+  [(ids, distances) or None] for queries ``q`` against the exact answers
+  over corpus ``x``; returns ``checks`` (each number beside its limit),
+  ``correct`` and ``hits`` (true top-k ids found, per answer);
+* ``malformed(ids, dists, rows, k)``: the count of answers that are not k
+  distinct in-range ids with finite distances in ascending order.  A
+  served distance is smaller for a nearer row under every metric;
+* ``control_answers(x, q, k)``: (ids, distances) of the reference one
+  precision lower, which ``compare`` must call not correct.
+
+Nothing here assumes that ``x`` lives on the device; the harness only
+hands it from the generator to the reference.
 
 Set-up (``setup_s``) runs from process start to the window's start: data
 generation on the device, ``Database.build``, warm-up calls of the served
 path.  The window drives ``ServingEngine.serve``.  After it, the system is
-freed and the plain reference (``reference.py``) judges the answers.  The
-last line of standard output is the result; the last lines of standard
-error give each number compared beside its limit.
+freed and the configuration's reference judges the answers.  The last line
+of standard output is the result; the last lines of standard error give
+each number compared beside its limit.
 """
 
 from __future__ import annotations
@@ -71,16 +100,29 @@ def cell_parts(man: dict, workload: str) -> dict:
              if workload in m.get("workloads", [workload])
              and m["moves"] in names]
     return {"cell": cell, "config": config, "mix": mix, "end_to_end": e2e,
-            "per_layer": layer}
+            "per_layer": layer, **semantics(config)}
+
+
+def semantics(config: dict) -> dict:
+    """The generator and the reference that a configuration names."""
+    return {"generator": load("generators", config["data"]["generator"]),
+            "reference": load("references", config["reference"])}
+
+
+def load(kind: str, name: str):
+    """``chipbench/<kind>/<name>.py``, loaded by its path once a process."""
+    key = f"chipbench_{kind}_{name}"
+    if key not in sys.modules:
+        spec = importlib.util.spec_from_file_location(
+            key, BENCH / kind / f"{name}.py")
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[key] = mod
+    return sys.modules[key]
 
 
 def load_metric(name: str):
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"chipbench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return load("metrics", name)
 
 
 def device_info(jax, chips: int, *, require_tpu: bool = True) -> dict:
@@ -113,20 +155,42 @@ class CompileCounter:
         self._jax.monitoring.unregister_event_duration_listener(self._on)
 
 
+class GcTimer:
+    """Times the Python collector's passes while armed, by generation."""
+
+    def __init__(self):
+        self.armed, self.passes, self._t = False, [], None
+        gc.callbacks.append(self._on)
+
+    def _on(self, phase, info):
+        if phase == "start":
+            self._t = time.perf_counter()
+        elif self.armed and self._t is not None:
+            self.passes.append((info["generation"],
+                                time.perf_counter() - self._t))
+
+    def summary(self) -> dict:
+        return {g: [sum(1 for p in self.passes if p[0] == g),
+                    sum(p[1] for p in self.passes if p[0] == g)]
+                for g in range(3)}
+
+    def close(self) -> None:
+        gc.callbacks.remove(self._on)
+
+
 @contextlib.contextmanager
 def _no_annotation(*_, **__):
     yield
 
 
 def build(parts: dict, seed: int):
-    """Data on the device, ``Database.build``, the engine.  Returns
-    (engine, rows spec)."""
+    """Data from the configuration's generator, ``Database.build``, the
+    engine.  Returns (engine, data spec)."""
     import jax
-    from chipbench import data
     from repro.anns import Database, PipelineConfig, QueryPlan
     from repro.serving import ServingEngine
 
-    cfg, mix = parts["config"], parts["mix"]
+    cfg, mix, data = parts["config"], parts["mix"], parts["generator"]
     spec = data.Spec(cfg)
     t = time.perf_counter()
     x = jax.block_until_ready(data.corpus(spec))
@@ -149,12 +213,13 @@ def run_cell(parts: dict, seed: int, seconds: float, trace: bool, *,
     ``system(parts, seed)`` makes what the window drives (the control puts
     the reference there); ``engine_hook`` may wrap it after set-up."""
     import jax
-    from chipbench import data, loadgen, reference, xtrace
+    from chipbench import loadgen, xtrace
 
     t_start = T0 if t_start is None else t_start
     cfg, mix, chips = parts["config"], parts["mix"], parts["cell"]["chips"]
+    data, reference = parts["generator"], parts["reference"]
     device = device_info(jax, chips, require_tpu=require_tpu)
-    counter = CompileCounter(jax)
+    counter, collector = CompileCounter(jax), GcTimer()
     annotate = jax.profiler.TraceAnnotation if trace else _no_annotation
 
     engine, spec = system(parts, seed)
@@ -176,11 +241,12 @@ def run_cell(parts: dict, seed: int, seconds: float, trace: bool, *,
         opts.python_tracer_level = 0
         jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
     setup_s = time.perf_counter() - t_start
-    counter.armed = True
+    counter.armed = collector.armed = True
     with annotate(xtrace.WINDOW):
         win = loadgen.run_window(engine, mix, seed, seconds, qs, annotate)
-    counter.armed = False
+    counter.armed = collector.armed = False
     counter.close()
+    collector.close()
     if trace:
         jax.profiler.stop_trace()
     peak = memory(jax, chips, "peak_bytes_in_use")
@@ -197,8 +263,8 @@ def run_cell(parts: dict, seed: int, seconds: float, trace: bool, *,
     k = cfg["pipeline"]["final_k"]
     limits = dict(cfg["limits"],
                   recall_at_10_min=cfg["guarantees"]["recall_at_10_min"])
-    verdict = reference.compare(answers, x, jax.numpy.asarray(q),
-                                rows=spec.rows, k=k, limits=limits)
+    verdict = reference.compare(answers, x, q, rows=spec.rows, k=k,
+                                limits=limits)
     del x
     all_bad = reference.malformed([a[0] if a else None for a in win.answers],
                                   [a[1] if a else None for a in win.answers],
@@ -226,7 +292,9 @@ def run_cell(parts: dict, seed: int, seconds: float, trace: bool, *,
             len(lat), "calls": len(win.sends), "window_s":
             win.returns[-1] - win.sends[0], "bytes_in_use": in_use,
             "peak_bytes_in_use": peak, "window_compiles": counter.count,
-            "call_s": [r - s for s, r in zip(win.sends, win.returns)]}
+            "window_gc": collector.summary(),
+            "call_s": [r - s for s, r in zip(win.sends, win.returns)],
+            "gap_s": [s - r for s, r in zip(win.sends[1:], win.returns)]}
 
     metrics, extra = {}, {}
     if not trace:

@@ -9,7 +9,7 @@ import time
 
 import pytest
 
-from chipbench import data, run
+from chipbench import run
 from chipbench.tests import tiny
 
 
@@ -89,6 +89,7 @@ def test_probed_candidates_match_the_front_counter(served):
     res, engine = served
     roof = run.load_metric("refine_kernel_roofline")
     index = run.probe_index(engine, tiny.parts()["config"])
+    data = tiny.parts()["generator"]
     spec = data.Spec(tiny.parts()["config"])
     batches = list(res["window"].batches.values())
     got = [roof.probed_candidates(index, data.queries(spec, b["qidx"]))

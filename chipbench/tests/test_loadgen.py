@@ -48,6 +48,16 @@ def test_query_indices_follow_the_seed():
         assert sorted(b[k:k + 128]) == list(range(k, k + 128))
 
 
+@pytest.mark.parametrize("recall_set,callers", [(128, 64), (256, 64),
+                                                (100, 64), (32, 48)])
+def test_the_window_order_is_query_indices(recall_set, callers):
+    m = mix(recall_set=recall_set, callers=callers)
+    order = loadgen.Order(m, SEED)
+    got = np.concatenate([order.take(j, callers)
+                          for j in range(0, 20 * callers, callers)])
+    assert np.array_equal(got, loadgen.query_indices(m, SEED, 20 * callers))
+
+
 def test_queries_are_made_from_the_seed_in_blocks():
     calls = []
 

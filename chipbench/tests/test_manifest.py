@@ -1,6 +1,7 @@
 """``BENCHMARK.json`` keeps to the benchmark's rules, and the harness finds
 every cell's configuration, traffic and metric files by name."""
 
+import ast
 import json
 import math
 import re
@@ -118,3 +119,59 @@ def test_peaks_are_keyed_by_device_kind():
 def test_four_chip_cells_stay_within_half():
     four = sum(w["chips"] == 4 for w in MAN["workloads"])
     assert four <= max(1, math.floor(len(CELLS) / 2))
+
+
+GENERATOR_API = ("Spec", "corpus", "build_key", "queries")
+REFERENCE_API = ("compare", "malformed", "control_answers")
+
+
+def _modules(kind):
+    return sorted(p.stem for p in (run.BENCH / kind).glob("*.py"))
+
+
+@pytest.mark.parametrize("config", MAN["configs"], ids=lambda c: c["name"])
+def test_every_config_names_its_generator_and_reference(config):
+    cfg = json.loads((run.ROOT / config["file"]).read_text())
+    assert cfg["data"]["generator"] in _modules("generators")
+    assert cfg["reference"] in _modules("references")
+    sem = run.semantics(cfg)
+    for name in GENERATOR_API:
+        assert callable(getattr(sem["generator"], name))
+    for name in REFERENCE_API:
+        assert callable(getattr(sem["reference"], name))
+    assert sem["generator"].Spec(cfg).rows == cfg["rows"]
+
+
+@pytest.mark.parametrize("kind,name",
+                         [("generators", n) for n in _modules("generators")]
+                         + [("references", n) for n in _modules("references")])
+def test_every_generator_and_reference_keeps_to_the_protocol(kind, name):
+    mod = run.load(kind, name)
+    api = GENERATOR_API if kind == "generators" else REFERENCE_API
+    for attr in api:
+        assert callable(getattr(mod, attr)), f"{kind}/{name}.py: {attr}"
+    assert run.load(kind, name) is mod            # loaded once a process
+
+
+@pytest.mark.parametrize("script", ["run.py", "control.py"])
+def test_the_harness_names_no_generator_or_reference(script):
+    """``run.py`` and ``control.py`` find a generator and a reference only
+    through the configuration: no import of either, and no module's name
+    written into the code."""
+    tree = ast.parse((run.BENCH / script).read_text())
+    names = set(_modules("generators") + _modules("references"))
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom):
+            mods = [node.module or ""] + [f"{node.module}.{a.name}"
+                                          for a in node.names]
+            mods += [a.name for a in node.names]
+        elif isinstance(node, ast.Import):
+            mods = [a.name for a in node.names]
+        else:
+            mods = []
+        for m in mods:
+            parts = set(m.split("."))
+            assert not parts & (names | {"generators", "references", "data",
+                                         "reference"}), (script, m)
+        if isinstance(node, ast.Constant) and isinstance(node.value, str):
+            assert node.value not in names, (script, node.value)

@@ -1,11 +1,16 @@
-"""The plain reference and the comparison that decides ``correct``."""
+"""The squared-L2 reference (``references/l2.py``) over the
+``clustered_unit`` generator's data, and the comparison that decides
+``correct``."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import data, reference
+from chipbench import run
+
+data = run.load("generators", "clustered_unit")
+reference = run.load("references", "l2")
 
 DATA = {"corpus_seed": 5, "clusters": 8, "spread": 0.35, "decay": 0.7,
         "decay_dims": 2, "query_noise": 0.25}

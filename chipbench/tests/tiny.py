@@ -21,3 +21,16 @@ def parts(**mix) -> dict:
                               "backend": "pallas", "warmup_calls": 1,
                               "recall_set": 32, "check_sample": 48, **mix})
     return p
+
+
+def ood_parts(**mix) -> dict:
+    """The same cell's system on ``ood_ip`` data, judged by the inner-product
+    reference: the data and the reference of another metric, at the tiny
+    cell's shape."""
+    p = parts(**mix)
+    cfg = p["config"]
+    cfg["data"] = {"generator": "ood_ip",
+                   **run.load("generators", "ood_ip").DEFAULTS}
+    cfg["reference"] = "ip"
+    p.update(run.semantics(cfg))
+    return p
